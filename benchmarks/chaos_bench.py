@@ -178,8 +178,7 @@ def _serve(n_requests: int, max_new: int, injector: Optional[FaultInjector]) -> 
         n_layers=cfg.n_layers, max_seqs=2, max_blocks_per_seq=8,
         blocks_per_arena=8, policy="puma", dtype="float32",
     )
-    eng = ServeEngine(model, params, pool_cfg, use_kernel=False,
-                      injector=injector)
+    eng = ServeEngine(model, params, pool_cfg, injector=injector)
     rng = np.random.default_rng(CHAOS_SEED)
     for i in range(n_requests):
         eng.submit(Request(rid=i, prompt=list(rng.integers(0, 64, 10)),

@@ -71,8 +71,7 @@ def make_engine(scenario):
     )
     base.update(scenario.pool_overrides())
     return ServeEngine(
-        model, params, KVPoolConfig(**base),
-        use_kernel=False, maintenance=MaintenanceConfig(),
+        model, params, KVPoolConfig(**base), maintenance=MaintenanceConfig(),
     )
 
 

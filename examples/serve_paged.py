@@ -40,7 +40,7 @@ def main():
             max_blocks_per_seq=16, blocks_per_arena=32,
             policy=policy, dtype="float32",
         )
-        eng = ServeEngine(model, params, pool_cfg, use_kernel=False)
+        eng = ServeEngine(model, params, pool_cfg)
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=p, max_new=args.max_new))
         t0 = time.perf_counter()

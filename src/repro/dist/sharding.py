@@ -35,7 +35,6 @@ from jax.sharding import PartitionSpec as P
 __all__ = [
     "PARAM_RULES",
     "ACT_RULES",
-    "shard_map_compat",
     "get_mesh",
     "set_mesh",
     "use_mesh",
@@ -47,19 +46,6 @@ __all__ = [
     "override_rules",
     "override_param_rules",
 ]
-
-try:  # jax >= 0.6
-    from jax import shard_map as shard_map_compat
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map_compat(f, **kw):
-        """``jax.shard_map`` across jax versions (older jax spells the
-        ``check_vma`` kwarg ``check_rep`` and lives under experimental)."""
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_old(f, **kw)
-
 
 #: logical parameter axis -> mesh axis (or tuple of axes, or None=replicated).
 #: "embed" carries FSDP ("data"); the tensor-parallel dims ride "model".

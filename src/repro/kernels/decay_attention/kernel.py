@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_INTERPRET = jax.devices()[0].platform != "tpu"
-
 MIN_LOG_DECAY = -1.8
 CHUNK = 32
 
@@ -102,5 +100,5 @@ def decay_attention(
         out_specs=spec_v,
         out_shape=jax.ShapeDtypeStruct((B, H, nc, Q, dv), q.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=_INTERPRET if interpret is None else interpret,
+        interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
     )(q, k, v, log_w, u)

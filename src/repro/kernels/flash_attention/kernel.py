@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_INTERPRET = jax.devices()[0].platform != "tpu"
-
 NEG_INF = -1e30
 
 
@@ -137,5 +135,5 @@ def flash_attention(
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
             pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
         ],
-        interpret=_INTERPRET if interpret is None else interpret,
+        interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
     )(q, k, v)

@@ -3,14 +3,17 @@
 One query token per sequence attends to its KV stream, which lives as
 ``block_size``-token pages scattered through the pool and addressed by a
 scalar-prefetched *block table* — the TPU replacement for the paper's
-re-mmap (DESIGN.md §2).  PUMA placement makes consecutive table entries
-contiguous, which turns consecutive grid steps' DMAs into sequential HBM
-streams (the hardware prefetcher's fast path); the kernel itself is
-placement-agnostic.
+re-mmap.  PUMA placement makes consecutive table entries contiguous, which
+turns consecutive grid steps' DMAs into sequential HBM streams (the
+hardware prefetcher's fast path); the kernel itself is placement-agnostic.
 
-GQA layout: queries are grouped per KV head — grid (batch, kv_heads,
-max_blocks), q block (group, head_dim) — so each MXU op serves a whole
-query-head group against one KV page.
+Layout: the grid is (batch, max_blocks) and each step stages one whole
+page — every KV head of it, block ``(1, block_size, Hkv, D)`` — so the
+block's two minor dims equal the pool's and the tiling is legal for any
+head count.  Queries come in group-major, ``(group, Hkv, D)``, so that
+each page token's ``(Hkv, D)`` K/V tile lines up with every query group
+by broadcasting over leading dims only.  Scores are lane reductions on the
+VPU: a decode step has one query row per head, too little to fill the MXU.
 """
 from __future__ import annotations
 
@@ -21,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_INTERPRET = jax.devices()[0].platform != "tpu"
-
 NEG_INF = -1e30
 
 
@@ -31,7 +32,8 @@ def _paged_kernel(
     *, scale, block_size, n_blocks,
 ):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    n_valid = lens_ref[b] - j * block_size      # tokens of this page in use
 
     @pl.when(j == 0)
     def _init():
@@ -39,35 +41,29 @@ def _paged_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (group, d)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (block_size, d)
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (block_size, d)
+    @pl.when(n_valid > 0)
+    def _page():
+        q = q_ref[0].astype(jnp.float32)[:, None]          # (G, 1, KV, D)
+        k = k_ref[0].astype(jnp.float32)[None]             # (1, bs, KV, D)
+        v = v_ref[0].astype(jnp.float32)[None]
+        s = jnp.sum(q * k, axis=-1, keepdims=True) * scale  # (G, bs, KV, 1)
+        tok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = tok < n_valid
+        s = jnp.where(mask, s, NEG_INF)
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                      # (group, block_size)
-
-    pos = j * block_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = pos < lens_ref[b]
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_scr[:, 0]
-    m_cur = jnp.maximum(m_prev, s.max(axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
-    l_cur = alpha * l_scr[:, 0] + p.sum(axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_scr[...] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
+        m_prev = m_scr[:, :, :1]                           # (G, KV, 1)
+        m_cur = jnp.maximum(m_prev, s.max(axis=1))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(mask, jnp.exp(s - m_cur[:, None]), 0.0)
+        l_cur = alpha * l_scr[:, :, :1] + p.sum(axis=1)
+        acc_scr[...] = acc_scr[...] * alpha + (p * v).sum(axis=1)
+        m_scr[...] = jnp.broadcast_to(m_cur, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_cur, l_scr.shape)
 
     @pl.when(j == n_blocks - 1)
     def _fin():
-        l = l_scr[:, :1]
-        o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype
-        )
+        l = l_scr[:, :, :1]
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -85,33 +81,35 @@ def paged_attention(
     _, block_size, _, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
 
-    def kv_index(b, h, j, tbl, lens):
+    def kv_index(b, j, tbl, lens):
         # -1 (pad) entries clamp to block 0; masking zeroes their weight.
-        return (jnp.maximum(tbl[b, j], 0), 0, h, 0)
+        return (jnp.maximum(tbl[b, j], 0), 0, 0, 0)
+
+    def q_index(b, j, tbl, lens):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, max_blocks),
+        grid=(B, max_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, h, j, tbl, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, D), kv_index),
-            pl.BlockSpec((1, block_size, 1, D), kv_index),
+            pl.BlockSpec((1, group, Hkv, D), q_index),
+            pl.BlockSpec((1, block_size, Hkv, D), kv_index),
+            pl.BlockSpec((1, block_size, Hkv, D), kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, D), lambda b, h, j, tbl, lens: (b, h, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, group, Hkv, D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((group, Hkv, 128), jnp.float32),
+            pltpu.VMEM((group, Hkv, 128), jnp.float32),
+            pltpu.VMEM((group, Hkv, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_kernel, scale=scale, block_size=block_size, n_blocks=max_blocks
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
-        interpret=_INTERPRET if interpret is None else interpret,
-    )(block_tables, seq_lens, q, k_pool, v_pool)
+        out_shape=jax.ShapeDtypeStruct((B, group, Hkv, D), q.dtype),
+        interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
+    )(block_tables, seq_lens, q.transpose(0, 2, 1, 3), k_pool, v_pool)
+    return out.transpose(0, 2, 1, 3)

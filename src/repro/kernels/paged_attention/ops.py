@@ -1,5 +1,4 @@
-"""Wrapper: (B, Hq, D) query layout -> grouped kernel layout, with sublane
-padding of the query-head group."""
+"""Wrapper: (B, Hq, D) query layout -> grouped (B, Hkv, group, D) layout."""
 from __future__ import annotations
 
 import jax
@@ -22,24 +21,12 @@ def paged_attention(
     B, Hq, D = q.shape
     Hkv = k_pool.shape[2]
     assert Hq % Hkv == 0
-    group = Hq // Hkv
     scale = (D ** -0.5) if scale is None else scale
-    qg = q.reshape(B, Hkv, group, D)
-    # pad the group dim to the 8-row sublane so VMEM scratch tiles cleanly
-    gpad = (-group) % 8
-    if gpad and use_kernel:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gpad), (0, 0)))
-    if use_kernel:
-        out = _k.paged_attention(
-            qg, k_pool, v_pool,
-            block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-            scale=scale,
-        )
-        out = out[:, :, :group]
-    else:
-        out = _ref.paged_attention_ref(
-            qg, k_pool, v_pool,
-            block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-            scale=scale,
-        )
+    qg = q.reshape(B, Hkv, Hq // Hkv, D)
+    fn = _k.paged_attention if use_kernel else _ref.paged_attention_ref
+    out = fn(
+        qg, k_pool, v_pool,
+        block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
+        scale=scale,
+    )
     return out.reshape(B, Hq, D)

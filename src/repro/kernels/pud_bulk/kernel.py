@@ -1,17 +1,17 @@
 """Pallas TPU kernels for PUD-style bulk row operations.
 
-TPU-native adaptation of the paper's substrate ops (DESIGN.md §2):
+TPU-native adaptation of the paper's substrate ops:
 
 * RowClone zero / copy       -> whole-tile VMEM stores / streams,
 * Ambit AND / OR / NOT       -> VPU bitwise ops on (8,128)-aligned int32
                                 tiles (packed bitplanes),
 * RowClone in-place block copy over a pool ("rows" = pool blocks) driven by
   a scalar-prefetched (src, dst) index list — the beam-fork / prefix-share
-  path of the PUMA KV pool.
+  path of the PUMA KV pool; one page per grid step.
 
-All kernels operate on buffers shaped (rows, 128): `rows` is a multiple of 8
-(sublane) and blocks of ``BLOCK_ROWS`` rows are staged through VMEM.  MXU is
-not involved — these are bandwidth ops; the roofline target is HBM bw, so
+The elementwise kernels operate on buffers shaped (rows, 128): `rows` is a
+multiple of 8 (sublane) and blocks of ``BLOCK_ROWS`` rows are staged through
+VMEM.  MXU is not involved — these are bandwidth ops; the roofline target is HBM bw, so
 the only tiling decision is a VMEM-resident block large enough to amortize
 grid overhead (256 rows x 128 lanes x 4 B = 128 KB per operand).
 """
@@ -26,8 +26,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256
 LANES = 128
-
-_INTERPRET = jax.devices()[0].platform != "tpu"
 
 
 def _grid(rows: int, block_rows: int) -> int:
@@ -110,7 +108,7 @@ def bulk_op(
         in_specs=[spec] * len(operands),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), dtype),
-        interpret=_INTERPRET if interpret is None else interpret,
+        interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
     )(*operands)
 
 
@@ -123,7 +121,7 @@ def _block_copy_kernel(src_dst_ref, pool_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def block_copy(
-    pool: jax.Array,          # (num_blocks, block_elems) — any dtype
+    pool: jax.Array,          # (num_blocks, *page_shape), ndim >= 3 — any dtype
     src_dst: jax.Array,       # (n_pairs, 2) int32
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -132,22 +130,23 @@ def block_copy(
     The (src, dst) list is scalar-prefetched so the BlockSpec index maps can
     steer both the read and the aliased write; untouched blocks pass through
     via input/output aliasing — the whole pool never round-trips through the
-    compute units, matching RowClone's in-DRAM semantics.
+    compute units, matching RowClone's in-DRAM semantics.  A grid step moves
+    one whole page: the block spans the page's full trailing dims, which is
+    what makes its tiling legal for any page shape.
     """
-    num_blocks, elems = pool.shape
+    page = pool.shape[1:]
     n_pairs = src_dst.shape[0]
+    zeros = (0,) * len(page)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_pairs,),
-        in_specs=[
-            pl.BlockSpec((1, elems), lambda i, sd: (sd[i, 0], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, elems), lambda i, sd: (sd[i, 1], 0)),
+        in_specs=[pl.BlockSpec((1, *page), lambda i, sd: (sd[i, 0], *zeros))],
+        out_specs=pl.BlockSpec((1, *page), lambda i, sd: (sd[i, 1], *zeros)),
     )
     return pl.pallas_call(
         _block_copy_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={1: 0},  # pool aliases the output
-        interpret=_INTERPRET if interpret is None else interpret,
+        interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
     )(src_dst, pool)

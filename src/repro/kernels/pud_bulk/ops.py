@@ -71,13 +71,13 @@ def pool_block_copy(
 ) -> jax.Array:
     """RowClone over a block pool: pool[dst] <- pool[src], in place.
 
-    ``pool``: (num_blocks, ...) — trailing dims are flattened per block.
+    ``pool``: (num_blocks, ...) — whole blocks are copied, any trailing
+    shape.
     """
-    nb = pool.shape[0]
-    flat = pool.reshape(nb, -1)
     src_dst = jnp.stack([src.astype(jnp.int32), dst.astype(jnp.int32)], axis=1)
-    if use_kernel:
-        out = _k.block_copy(flat, src_dst)
-    else:
-        out = _ref.block_copy_ref(flat, src_dst)
-    return out.reshape(pool.shape)
+    if not use_kernel:
+        return _ref.block_copy_ref(pool, src_dst)
+    # the kernel's page block needs two full minor dims: a flat pool gets a
+    # unit one (a pool of 3+ dims keeps its layout, no relayout copy)
+    paged = pool if pool.ndim >= 3 else pool.reshape(pool.shape[0], 1, -1)
+    return _k.block_copy(paged, src_dst).reshape(pool.shape)

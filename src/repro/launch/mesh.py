@@ -12,25 +12,21 @@ jax device state — the dry-run sets XLA_FLAGS before any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with ``AxisType.Auto`` where the installed jax has
-    it (``axis_types`` landed after 0.4.x); a plain mesh otherwise.  Keeps
-    one mesh-construction path working across the jax versions the repo
-    sees (CPU container vs real-hardware toolchains)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto`` (sharding left
+    to the compiler), the mode all of this repository's code is written for."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CPU integration tests (8 forced host devices)."""
-    return make_mesh_compat((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.dist.sharding import get_mesh, shard_map_compat as _shard_map
+from repro.dist.sharding import get_mesh
 from repro.models.params import ParamDef
 
 
@@ -169,7 +169,7 @@ def apply_moe(
             aux = jax.lax.pmean(aux, "model")
         return out.reshape(Bl, Sl, d), aux
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, router_spec, w_in_spec, w_in_spec, w_out_spec),

@@ -125,7 +125,6 @@ class ServeEngine:
         params,
         pool_cfg: KVPoolConfig,
         *,
-        use_kernel: bool = False,   # pallas-interpret is slow on CPU; jnp ref default
         jit: bool = True,           # compile prefill/decode per shape (load-
                                     # harness scale needs it; False = eager)
         eos_id: Optional[int] = None,
@@ -142,7 +141,9 @@ class ServeEngine:
         self.cfg = cfg
         self.params = params
         self.pool = PagedKVPool(pool_cfg, injector=injector)
-        self.use_kernel = use_kernel
+        #: the Pallas kernels on the chip; elsewhere the jnp reference (the
+        #: kernels would only run in the slow Pallas interpreter there)
+        self.use_kernel = jax.default_backend() == "tpu"
         self.jit = jit
         if jit:
             # cache the jitted prefill step ON the model so every engine

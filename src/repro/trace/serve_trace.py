@@ -91,8 +91,7 @@ def record_scenario(
         },
     )
     eng = ServeEngine(
-        model, params, pool_cfg,
-        use_kernel=False, maintenance=MaintenanceConfig(), trace=trace,
+        model, params, pool_cfg, maintenance=MaintenanceConfig(), trace=trace,
     )
     specs = sc.generate()
     if n_requests is not None:

@@ -59,8 +59,8 @@ def test_restore_validates_shapes(tmp_path):
 def test_restore_with_shardings(tmp_path):
     t = _tree()
     ckpt.save(str(tmp_path), 1, t)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"a": NamedSharding(mesh, P()), "b": {"c": NamedSharding(mesh, P())}}
     got = ckpt.restore(str(tmp_path), 1, t, shardings=sh)

@@ -269,8 +269,7 @@ def test_engine_maintenance_hook_fires_and_preserves_output():
     prompts = [list(rng.integers(0, cfg.vocab_size, 9)) for _ in range(4)]
 
     def drive(maint):
-        eng = ServeEngine(model, params, pool_cfg(), use_kernel=False,
-                          maintenance=maint)
+        eng = ServeEngine(model, params, pool_cfg(), maintenance=maint)
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=list(p), max_new=6))
         done = eng.run()

@@ -57,8 +57,8 @@ def test_moe_shard_map_matches_local():
                     jnp.float32)
     out_local, aux_local = MOE.apply_moe(params, cfg, x)
 
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     with shd.use_mesh(mesh):
         out_dist, aux_dist = MOE.apply_moe(params, cfg, x)
     np.testing.assert_allclose(

@@ -26,8 +26,8 @@ def test_parser_counts_and_bytes():
 
 
 def test_parser_on_real_module():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     x = jax.ShapeDtypeStruct((8, 8), jnp.float32,

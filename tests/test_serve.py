@@ -49,7 +49,7 @@ def _dense_generate(model, params, prompt, max_new):
 def test_paged_engine_matches_dense_decode(model_and_params):
     model, params = model_and_params
     cfg = model.cfg
-    eng = ServeEngine(model, params, _pool_cfg(cfg), use_kernel=False)
+    eng = ServeEngine(model, params, _pool_cfg(cfg))
     rng = np.random.default_rng(0)
     prompts = [
         list(rng.integers(0, cfg.vocab_size, size=int(rng.integers(4, 18))))
@@ -64,13 +64,19 @@ def test_paged_engine_matches_dense_decode(model_and_params):
         assert req.out == ref, (req.rid, req.out, ref)
 
 
+def test_engine_takes_kernel_path_only_on_tpu(model_and_params, monkeypatch):
+    model, params = model_and_params
+    assert jax.default_backend() == "cpu"
+    assert ServeEngine(model, params, _pool_cfg(model.cfg)).use_kernel is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ServeEngine(model, params, _pool_cfg(model.cfg)).use_kernel is True
+
+
 def test_continuous_batching_under_pressure(model_and_params):
     model, params = model_and_params
     cfg = model.cfg
     # tiny pool: forces queueing + admission as slots free up
-    eng = ServeEngine(
-        model, params, _pool_cfg(cfg, num_blocks=32, max_seqs=2), use_kernel=False
-    )
+    eng = ServeEngine(model, params, _pool_cfg(cfg, num_blocks=32, max_seqs=2))
     rng = np.random.default_rng(1)
     for i in range(5):
         eng.submit(Request(rid=i, prompt=list(rng.integers(0, 64, 6)), max_new=4))
@@ -84,7 +90,7 @@ def test_continuous_batching_under_pressure(model_and_params):
 def test_fork_shares_prefix(model_and_params):
     model, params = model_and_params
     cfg = model.cfg
-    eng = ServeEngine(model, params, _pool_cfg(cfg), use_kernel=False)
+    eng = ServeEngine(model, params, _pool_cfg(cfg))
     eng.submit(Request(rid=0, prompt=[1, 2, 3, 4, 5, 6, 7, 8, 9], max_new=4))
     # admit + prefill via one engine step
     eng.step()

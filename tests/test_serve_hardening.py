@@ -56,7 +56,7 @@ def _dense_generate(model, params, prompt, max_new):
 
 def test_never_admissible_request_rejected_at_submit(model_and_params):
     model, params = model_and_params
-    eng = ServeEngine(model, params, _pool_cfg(model.cfg), use_kernel=False)
+    eng = ServeEngine(model, params, _pool_cfg(model.cfg))
     # capacity: min(16, 16) blocks * 8 tokens = 128 tokens; ask for more
     with pytest.raises(RequestRejected) as ei:
         eng.submit(Request(rid=0, prompt=list(range(120)), max_new=20))
@@ -73,7 +73,7 @@ def test_never_admissible_request_rejected_at_submit(model_and_params):
 def test_stalled_queue_is_rejected_with_report(model_and_params):
     model, params = model_and_params
     inj = FaultInjector(FaultPlan(alloc_miss_rate=1.0))   # admission never works
-    eng = ServeEngine(model, params, _pool_cfg(model.cfg), use_kernel=False,
+    eng = ServeEngine(model, params, _pool_cfg(model.cfg),
                       injector=inj, stall_patience=2)
     eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new=2))
     with pytest.raises(RequestRejected) as ei:
@@ -84,7 +84,7 @@ def test_stalled_queue_is_rejected_with_report(model_and_params):
     assert not eng.queue and not eng.live                 # zero silent drops
     check_engine(eng).assert_ok()
     # the loud path is also visible without raising
-    done = ServeEngine(model, params, _pool_cfg(model.cfg), use_kernel=False,
+    done = ServeEngine(model, params, _pool_cfg(model.cfg),
                        injector=FaultInjector(FaultPlan(alloc_miss_rate=1.0)),
                        stall_patience=2)
     done.submit(Request(rid=0, prompt=[1, 2, 3], max_new=2))
@@ -94,8 +94,7 @@ def test_stalled_queue_is_rejected_with_report(model_and_params):
 
 def test_lookahead_admission_fixes_head_of_line_blocking(model_and_params):
     model, params = model_and_params
-    eng = ServeEngine(model, params, _pool_cfg(model.cfg, max_seqs=2),
-                      use_kernel=False)
+    eng = ServeEngine(model, params, _pool_cfg(model.cfg, max_seqs=2))
     rng = np.random.default_rng(2)
     big_prompt = list(rng.integers(0, 64, 90))     # 12 blocks: blocked early
     small_prompt = list(rng.integers(0, 64, 8))    # 1 block: always fits
@@ -113,8 +112,7 @@ def test_lookahead_admission_fixes_head_of_line_blocking(model_and_params):
 
 def test_deadline_cancels_queued_request(model_and_params):
     model, params = model_and_params
-    eng = ServeEngine(model, params, _pool_cfg(model.cfg, max_seqs=1),
-                      use_kernel=False)
+    eng = ServeEngine(model, params, _pool_cfg(model.cfg, max_seqs=1))
     eng.submit(Request(rid=0, prompt=[1, 2, 3, 4], max_new=8))
     eng.submit(Request(rid=1, prompt=[5, 6, 7, 8], max_new=4,
                        deadline_steps=2))       # expires while queued
@@ -135,7 +133,6 @@ def test_preemption_resumes_with_bit_exact_recompute(model_and_params):
         model, params,
         _pool_cfg(cfg, num_blocks=8, block_size=4, blocks_per_arena=8,
                   max_seqs=2, max_blocks_per_seq=8),
-        use_kernel=False,
     )
     rng = np.random.default_rng(3)
     prompts = [list(rng.integers(0, 64, 10)) for _ in range(2)]
@@ -181,9 +178,7 @@ def test_contended_run_matches_uncontended_bit_exactly(model_and_params, seed):
     reqs = [(len(p), p) for _, p in reqs]
 
     def run(pool_kw):
-        eng = ServeEngine(
-            model, params, _pool_cfg(cfg, **pool_kw), use_kernel=False,
-        )
+        eng = ServeEngine(model, params, _pool_cfg(cfg, **pool_kw))
         for i, (_, p) in enumerate(reqs):
             eng.submit(Request(rid=i, prompt=list(p), max_new=10))
         done = eng.run()
@@ -211,7 +206,7 @@ def test_step_hooks_get_isolated_snapshots(model_and_params):
     sample (as the watermark bookkeeping does) must not leak an
     inconsistent read into a sampler running in the same tick."""
     model, params = model_and_params
-    eng = ServeEngine(model, params, _pool_cfg(model.cfg), use_kernel=False)
+    eng = ServeEngine(model, params, _pool_cfg(model.cfg))
 
     seen_by_b = []
 

@@ -45,8 +45,7 @@ def _engine(model_and_params, overrides=()):
     )
     base.update(dict(overrides))
     return ServeEngine(
-        model, params, KVPoolConfig(**base),
-        use_kernel=False, maintenance=MaintenanceConfig(),
+        model, params, KVPoolConfig(**base), maintenance=MaintenanceConfig(),
     )
 
 

@@ -1,0 +1,101 @@
+"""Compile the main-path kernels and the paged decode step for a described
+TPU v5e — no chip needed, the TPU compiler refuses illegal tilings and
+programs that do not fit the way the chip would.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, and test workers all import this file.
+"""
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels.paged_attention import kernel as paged_k
+from repro.kernels.pud_bulk import kernel as pud_k
+from repro.launch.serve import pool_config
+from repro.models.transformer import LM
+from repro.serve.paged_runner import paged_decode_step
+
+V5E_HBM_BYTES = 16 * 10**9        # one v5e chip, published: 16 GB
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def spec(one_chip):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()     # the kernel, not a fallback
+    return compiled
+
+
+# (Hq, Hkv, D): stablelm-1.6b, chatglm3-6b, granite-moe-1b, granite-34b
+@pytest.mark.parametrize("hq,hkv,d", [(32, 32, 64), (32, 2, 128), (16, 8, 64), (48, 1, 128)])
+def test_paged_attention_compiles(spec, hq, hkv, d):
+    B, nb, bs, maxb = 8, 512, 16, 32
+    pool = spec((nb, bs, hkv, d), jnp.bfloat16)
+    _compile(
+        functools.partial(paged_k.paged_attention, scale=d ** -0.5, interpret=False),
+        spec((B, hkv, hq // hkv, d), jnp.bfloat16), pool, pool,
+        spec((B, maxb), jnp.int32), spec((B,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_block_copy_compiles_at_stablelm_page(spec, dtype):
+    # every layer's pages of a 512-page stablelm-1.6b pool: (L*nb, bs, KV, hd)
+    _compile(
+        functools.partial(pud_k.block_copy, interpret=False),
+        spec((24 * 512, 16, 32, 64), dtype), spec((24 * 9, 2), jnp.int32),
+    )
+
+
+def test_bulk_op_compiles(spec):
+    x = spec((1024, pud_k.LANES), jnp.int32)
+    _compile(functools.partial(pud_k.bulk_op, op="maj", interpret=False), x, x, x)
+
+
+def test_paged_decode_step_compiles_at_published_width(spec, monkeypatch):
+    # stablelm-1.6b at its published widths, depth cut to 2 layers
+    cfg = dataclasses.replace(get_config("stablelm_1_6b"), n_layers=2)
+    pc = pool_config(cfg, max_seqs=8)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(LM(cfg, attn_impl="naive", remat=None).init, jax.random.key(0)),
+    )
+    pool = spec(
+        (cfg.n_layers, pc.num_blocks, pc.block_size, pc.kv_heads, pc.head_dim),
+        jnp.dtype(pc.dtype),
+    )
+    B = 8
+    # the kernel picks interpret mode from the backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        compiled = _compile(
+            lambda p, *xs: paged_decode_step(p, cfg, *xs, use_kernel=True),
+            params, spec((B, 1), jnp.int32), spec((B, 1), jnp.int32), pool, pool,
+            spec((B, pc.max_blocks_per_seq), jnp.int32), spec((B,), jnp.int32),
+        )
+    finally:
+        jax.clear_caches()       # drop traces made while the backend was faked
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES

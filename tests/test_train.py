@@ -114,9 +114,9 @@ def test_compression_error_feedback_unbiased():
 
 
 def test_compressed_psum_on_one_device_mesh():
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("data",))
-    from repro.dist.sharding import shard_map_compat as shard_map
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
+    from jax import shard_map
     x = jnp.asarray(np.random.default_rng(1).normal(size=(8, 128)), jnp.float32)
     f = shard_map(
         lambda v: comp.compressed_psum(v, "data"),
