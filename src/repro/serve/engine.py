@@ -46,6 +46,24 @@ Open-loop load support (:mod:`repro.serve.loadgen` is the consumer):
 :meth:`ServeEngine.step_sample` after every step, and ``run_for`` /
 ``drain`` slice engine time so a traffic driver can interleave arrivals
 with bounded stepping instead of handing over the whole loop.
+
+Profiler spans: every phase of :meth:`ServeEngine.step` is a
+``jax.profiler.TraceAnnotation``, so a ``jax.profiler`` trace puts the
+engine's host work on the device's clock.  While no trace is taken one
+costs about half a microsecond on a TPU v5e host; the profiler's state is
+their only switch:
+
+  * ``serve.step`` — the whole ``step()``, hooks included; sequence
+    bookkeeping (token append, release) is its self time;
+  * ``serve.admit`` — the deadline sweep and the admission scan;
+  * ``serve.prefill`` (``rid``, ``tokens``) — one request's jitted prefill,
+    its ``write_prompt_kv`` loop and first-token argmax (inside ``admit``);
+  * ``serve.decode_dispatch`` (``batch``) — block tables, ``seq_lens``,
+    host-to-device inputs and the asynchronous call of the paged step;
+  * ``serve.sample`` — the host waiting for the step's argmax;
+  * ``serve.kv_writeback`` (``rid``) — one sequence's per-layer
+    ``write_token_kv`` calls;
+  * ``serve.maintain`` — a compaction pass, only when one runs.
 """
 from __future__ import annotations
 
@@ -56,6 +74,7 @@ from typing import TYPE_CHECKING, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.kv_pool import KVPoolConfig, PagedKVPool
@@ -184,9 +203,11 @@ class ServeEngine:
         self.compaction_passes = 0
         self.blocks_migrated = 0
         self._last_maintenance = -(10 ** 9)
-        #: tracegen recorder (:class:`repro.trace.record.TraceRecorder`):
-        #: shared with the pool so request lifecycle, prompt-KV fills,
-        #: decode-token writes, and compaction all land in one trace.
+        #: tracegen recorder (:class:`repro.trace.record.TraceRecorder`) of
+        #: simulated-DRAM accesses, not the profiler spans (those are always
+        #: there, see the module docstring): shared with the pool so request
+        #: lifecycle, prompt-KV fills, decode-token writes, and compaction
+        #: all land in one trace.
         self.trace = trace
         self.pool.trace = trace
         self._step_writes: List = []   # (slot, block) token writes this step
@@ -336,14 +357,15 @@ class ServeEngine:
         contig = self.pool.contiguity_report()["mean_contiguous_fraction"]
         if free_frac > mc.free_low and frag < mc.frag_high and contig > mc.contig_low:
             return
-        self._last_maintenance = self.clock
-        report = self.pool.compact(
-            max_moves=mc.max_moves, use_kernel=self.use_kernel
-        )
-        if report is not None and report.executed:
-            self.compaction_passes += 1
-            self.blocks_migrated += report.executed
-            self.maintenance_ns += report.total_ns
+        with TraceAnnotation("serve.maintain"):
+            self._last_maintenance = self.clock
+            report = self.pool.compact(
+                max_moves=mc.max_moves, use_kernel=self.use_kernel
+            )
+            if report is not None and report.executed:
+                self.compaction_passes += 1
+                self.blocks_migrated += report.executed
+                self.maintenance_ns += report.total_ns
 
     # -- prefill --------------------------------------------------------------
     def _prefill(self, req: Request) -> bool:
@@ -353,23 +375,24 @@ class ServeEngine:
         rejected (pathological: pool cannot host the sampled token)."""
         cfg = self.cfg
         ctx = req.prompt + req.out[:-1]
-        toks = jnp.asarray([ctx], jnp.int32)
-        S = toks.shape[1]
-        pos = jnp.arange(S, dtype=jnp.int32)[None]
-        cache = self.model.init_cache(1, S, recent_size=S)
-        batch = {"tokens": toks, "positions": pos}
-        logits, cache = self._decode_step(self.params, batch, cache)
-        self.tokens_prefilled += S
-        # prompt KV lands in the recent ring (split cache, len_main == 0)
-        k, v = cache["layers"]["recent"]            # (L, 1, S, KV, hd)
-        for li in range(cfg.n_layers):
-            self.pool.write_prompt_kv(req.slot, li, k[li, 0, :S], v[li, 0, :S])
-        if self.trace is not None:
-            self.trace.on_prefill(
-                req.slot, req.rid, S, self.pool.tiles_of(req.slot)
-            )
-        if not req.out:
-            req.out.append(int(jnp.argmax(logits[0])))
+        with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(ctx)):
+            toks = jnp.asarray([ctx], jnp.int32)
+            S = toks.shape[1]
+            pos = jnp.arange(S, dtype=jnp.int32)[None]
+            cache = self.model.init_cache(1, S, recent_size=S)
+            batch = {"tokens": toks, "positions": pos}
+            logits, cache = self._decode_step(self.params, batch, cache)
+            self.tokens_prefilled += S
+            # prompt KV lands in the recent ring (split cache, len_main == 0)
+            k, v = cache["layers"]["recent"]            # (L, 1, S, KV, hd)
+            for li in range(cfg.n_layers):
+                self.pool.write_prompt_kv(req.slot, li, k[li, 0, :S], v[li, 0, :S])
+            if self.trace is not None:
+                self.trace.on_prefill(
+                    req.slot, req.rid, S, self.pool.tiles_of(req.slot)
+                )
+            if not req.out:
+                req.out.append(int(jnp.argmax(logits[0])))
         # account the pending token: it becomes the next decode input.
         # allow_preempt=False — admission must never evict decode progress
         # (see _append_with_recovery); the admission gate below makes this
@@ -399,51 +422,53 @@ class ServeEngine:
         snapshot copy: a consumer that mutates its sample — or registers /
         removes hooks from inside one — cannot leak an inconsistent view
         into the other consumers mid-iteration."""
-        if self.trace is not None:
-            self._step_writes = []
-            d0 = self.tokens_decoded
-        alive = self._step()
-        if self.trace is not None:
-            self.trace.on_step(
-                self.clock, self.tokens_decoded - d0, self._step_writes
-            )
-        if self.step_hooks:
-            sample = self.step_sample()
-            for hook in list(self.step_hooks):
-                hook(self, dict(sample))
-        return alive
+        with TraceAnnotation("serve.step"):
+            if self.trace is not None:
+                self._step_writes = []
+                d0 = self.tokens_decoded
+            alive = self._step()
+            if self.trace is not None:
+                self.trace.on_step(
+                    self.clock, self.tokens_decoded - d0, self._step_writes
+                )
+            if self.step_hooks:
+                sample = self.step_sample()
+                for hook in list(self.step_hooks):
+                    hook(self, dict(sample))
+            return alive
 
     def _step(self) -> bool:
         self.clock += 1
-        self._sweep_deadlines()
+        with TraceAnnotation("serve.admit"):
+            self._sweep_deadlines()
 
-        # 1) admit — bounded lookahead so a large head request cannot starve
-        #    admissible smaller requests behind it (HOL-blocking fix)
-        idx = 0
-        scanned = 0
-        while idx < len(self.queue) and scanned < self.admission_lookahead:
-            req = self.queue[idx]
-            slot = self.pool.admit(req.ctx_tokens())
-            if slot is None:
-                idx += 1
-                scanned += 1
-                continue
-            # prefill appends the sampled token immediately: if that needs a
-            # growth block the pool doesn't have, admitting now would either
-            # reject the request or evict running work — leave it queued.
-            if (self.pool.pool.free_tiles() == 0
-                    and self.pool.blocks_for(req.ctx_tokens() + 1)
-                    > self.pool.blocks_for(req.ctx_tokens())):
-                self.pool.release(slot)
-                idx += 1
-                scanned += 1
-                continue
-            del self.queue[idx]
-            req.slot = slot
-            req.status = "running"
-            req.admit_clock = self.clock
-            self.live[slot] = req
-            self._prefill(req)
+            # 1) admit — bounded lookahead so a large head request cannot starve
+            #    admissible smaller requests behind it (HOL-blocking fix)
+            idx = 0
+            scanned = 0
+            while idx < len(self.queue) and scanned < self.admission_lookahead:
+                req = self.queue[idx]
+                slot = self.pool.admit(req.ctx_tokens())
+                if slot is None:
+                    idx += 1
+                    scanned += 1
+                    continue
+                # prefill appends the sampled token immediately: if that needs a
+                # growth block the pool doesn't have, admitting now would either
+                # reject the request or evict running work — leave it queued.
+                if (self.pool.pool.free_tiles() == 0
+                        and self.pool.blocks_for(req.ctx_tokens() + 1)
+                        > self.pool.blocks_for(req.ctx_tokens())):
+                    self.pool.release(slot)
+                    idx += 1
+                    scanned += 1
+                    continue
+                del self.queue[idx]
+                req.slot = slot
+                req.status = "running"
+                req.admit_clock = self.clock
+                self.live[slot] = req
+                self._prefill(req)
 
         if not self.live:
             if not self.queue:
@@ -471,28 +496,31 @@ class ServeEngine:
         # 2) fused decode for all live sequences
         slots = sorted(self.live)
         cfg = self.cfg
-        tbl_full = self.pool.block_table()
-        lens_full = self.pool.seq_lens()
-        tokens = np.array([[self.live[s].out[-1]] for s in slots], np.int32)
-        positions = np.array([[lens_full[s] - 1] for s in slots], np.int32)
-        tbl = jnp.asarray(tbl_full[slots])
-        lens = jnp.asarray(lens_full[slots])
+        with TraceAnnotation("serve.decode_dispatch", batch=len(slots)):
+            tbl_full = self.pool.block_table()
+            lens_full = self.pool.seq_lens()
+            tokens = np.array([[self.live[s].out[-1]] for s in slots], np.int32)
+            positions = np.array([[lens_full[s] - 1] for s in slots], np.int32)
+            tbl = jnp.asarray(tbl_full[slots])
+            lens = jnp.asarray(lens_full[slots])
 
-        logits, new_k, new_v = self._paged_step(
-            self.params, cfg,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            self.pool.k, self.pool.v, tbl, lens,
-            use_kernel=self.use_kernel,
-        )
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            logits, new_k, new_v = self._paged_step(
+                self.params, cfg,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                self.pool.k, self.pool.v, tbl, lens,
+                use_kernel=self.use_kernel,
+            )
+        with TraceAnnotation("serve.sample"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
 
         # 3) write current-token KV into PUMA-placed blocks, advance seqs
         for bi, slot in enumerate(slots):
             if slot not in self.live:
                 continue                    # preempted earlier this loop
             req = self.live[slot]
-            for li in range(cfg.n_layers):
-                self.pool.write_token_kv(slot, li, new_k[li, bi], new_v[li, bi])
+            with TraceAnnotation("serve.kv_writeback", rid=req.rid):
+                for li in range(cfg.n_layers):
+                    self.pool.write_token_kv(slot, li, new_k[li, bi], new_v[li, bi])
             if self.trace is not None:
                 # one block-granular write per decoded token (all layers'
                 # planes of that block count as the one row touch)

@@ -9,6 +9,13 @@ The runner mirrors ``LM.decode_step`` exactly (same params, same math) with
 the dense cache swapped for (k_pool, v_pool, block_table, seq_lens); layer
 loop is unrolled (serving configs are small; the dry-run path uses the
 scanned dense-cache step).
+
+Each layer's parts carry a ``jax.named_scope`` — ``attn_proj`` (norm, q/k/v
+projections, rope), ``paged_attention`` (the kernel and the merge of the
+current token), ``paged_lse`` (the second pass for the log-sum-exp),
+``attn_out`` and ``mlp`` — and the final norm and head carry ``logits``.
+Scopes only name the operations in the program's metadata, so a profiler
+trace can split the step's device time by part.
 """
 from __future__ import annotations
 
@@ -57,12 +64,13 @@ def paged_decode_step(
     n_layers = cfg.n_layers
     for li in range(n_layers):
         lp = jax.tree.map(lambda a: a[li], params["layers"])
-        h = L.apply_norm(lp["ln1"], x)
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"].astype(dtype))
-        k1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wk"].astype(dtype))
-        v1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wv"].astype(dtype))
-        q = apply_rope(cfg, q, positions)
-        k1 = apply_rope(cfg, k1, positions)
+        with jax.named_scope("attn_proj"):
+            h = L.apply_norm(lp["ln1"], x)
+            q = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wq"].astype(dtype))
+            k1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wk"].astype(dtype))
+            v1 = jnp.einsum("bsd,dhk->bshk", h, lp["attn"]["wv"].astype(dtype))
+            q = apply_rope(cfg, q, positions)
+            k1 = apply_rope(cfg, k1, positions)
 
         # overlay: extend each sequence's KV stream with the current token by
         # appending a virtual block holding it at position seq_len-1.
@@ -71,19 +79,22 @@ def paged_decode_step(
             k1[:, 0].astype(k_pool.dtype), v1[:, 0].astype(v_pool.dtype),
             use_kernel=use_kernel,
         )
-        a = jnp.einsum("bhk,hkd->bd", attn_out, lp["attn"]["wo"].astype(dtype))
-        x = x + a[:, None]
-        h = L.apply_norm(lp["ln2"], x)
-        if cfg.n_experts:
-            m, _ = MOE.apply_moe(lp["moe"], cfg, h)
-        else:
-            m = L.apply_mlp(lp["mlp"], h)
-        x = x + m
+        with jax.named_scope("attn_out"):
+            a = jnp.einsum("bhk,hkd->bd", attn_out, lp["attn"]["wo"].astype(dtype))
+            x = x + a[:, None]
+        with jax.named_scope("mlp"):
+            h = L.apply_norm(lp["ln2"], x)
+            if cfg.n_experts:
+                m, _ = MOE.apply_moe(lp["moe"], cfg, h)
+            else:
+                m = L.apply_mlp(lp["mlp"], h)
+            x = x + m
         new_ks.append(k1[:, 0])
         new_vs.append(v1[:, 0])
 
-    x = L.apply_norm(params["final_ln"], x)
-    logits = L.logits_from(params["embed"], x)[:, 0]
+    with jax.named_scope("logits"):
+        x = L.apply_norm(params["final_ln"], x)
+        logits = L.logits_from(params["embed"], x)[:, 0]
     return logits, jnp.stack(new_ks), jnp.stack(new_vs)
 
 
@@ -108,28 +119,31 @@ def _paged_attention_with_current(
 
     # past contribution (lengths exclude the current token)
     past_len = seq_lens - 1
-    out_past = paged_ops.paged_attention(
-        q, k_pool, v_pool, block_tables, past_len,
-        scale=scale, use_kernel=use_kernel,
-    )                                                     # (B, H, hd)
+    with jax.named_scope("paged_attention"):
+        out_past = paged_ops.paged_attention(
+            q, k_pool, v_pool, block_tables, past_len,
+            scale=scale, use_kernel=use_kernel,
+        )                                                 # (B, H, hd)
 
-    # merge current token: softmax over [past, current] decomposes into
-    # weighted average of past attention output and v_cur.
-    qg = q.reshape(B, KV, group, hd).astype(jnp.float32)
-    s_cur = jnp.einsum("bkgd,bkd->bkg", qg, k_cur.astype(jnp.float32)) * scale
+        # merge current token: softmax over [past, current] decomposes into
+        # weighted average of past attention output and v_cur.
+        qg = q.reshape(B, KV, group, hd).astype(jnp.float32)
+        s_cur = jnp.einsum("bkgd,bkd->bkg", qg, k_cur.astype(jnp.float32)) * scale
 
     # recompute the past logsumexp (cheap second pass over logits only)
-    lse_past = _paged_lse(q, k_pool, block_tables, past_len, scale)  # (B,KV,group)
-    has_past = (past_len > 0)[:, None, None]
-    m = jnp.maximum(jnp.where(has_past, lse_past, -jnp.inf), s_cur)
-    w_past = jnp.where(has_past, jnp.exp(lse_past - m), 0.0)
-    w_cur = jnp.exp(s_cur - m)
-    denom = w_past + w_cur
-    out = (
-        out_past.reshape(B, KV, group, hd).astype(jnp.float32) * w_past[..., None]
-        + v_cur.astype(jnp.float32)[:, :, None, :] * w_cur[..., None]
-    ) / denom[..., None]
-    return out.reshape(B, H, hd).astype(q.dtype)
+    with jax.named_scope("paged_lse"):
+        lse_past = _paged_lse(q, k_pool, block_tables, past_len, scale)  # (B,KV,group)
+    with jax.named_scope("paged_attention"):
+        has_past = (past_len > 0)[:, None, None]
+        m = jnp.maximum(jnp.where(has_past, lse_past, -jnp.inf), s_cur)
+        w_past = jnp.where(has_past, jnp.exp(lse_past - m), 0.0)
+        w_cur = jnp.exp(s_cur - m)
+        denom = w_past + w_cur
+        out = (
+            out_past.reshape(B, KV, group, hd).astype(jnp.float32) * w_past[..., None]
+            + v_cur.astype(jnp.float32)[:, :, None, :] * w_cur[..., None]
+        ) / denom[..., None]
+        return out.reshape(B, H, hd).astype(q.dtype)
 
 
 def _paged_lse(q, k_pool, block_tables, seq_lens, scale):
