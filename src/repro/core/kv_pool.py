@@ -20,7 +20,8 @@ placement translates directly into fewer descriptors.
 from __future__ import annotations
 
 import dataclasses
-from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
+import functools
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -248,12 +249,6 @@ class PagedKVPool:
         """Current tile list of a live sequence (trace emission)."""
         return list(self._seqs[slot][0].tiles)
 
-    def block_of_token(self, slot: int) -> int:
-        """Pool block holding the sequence's latest token — the block a
-        decode-step ``write_token_kv`` just landed in."""
-        h, ntok = self._seqs[slot]
-        return h.tiles[(ntok - 1) // self.cfg.block_size]
-
     # -- device views -----------------------------------------------------------
     def block_table(self) -> np.ndarray:
         """(max_seqs, max_blocks) int32, -1 padded."""
@@ -270,34 +265,39 @@ class PagedKVPool:
             out[slot] = ntok
         return out
 
-    def write_prompt_kv(
-        self, slot: int, layer: int, k: jax.Array, v: jax.Array
-    ) -> None:
-        """Scatter a prompt's K/V (n_tokens, kv_heads, head_dim) into the pool."""
-        cfg = self.cfg
+    def write_prompt_kv(self, slot: int, k: jax.Array, v: jax.Array) -> None:
+        """Write a prompt's K/V of every layer, ``(n_layers, n_tokens,
+        kv_heads, head_dim)`` each, into the sequence's pages in one in-place
+        call; the last page's tail past ``n_tokens`` is zero.  A batch axis
+        of one after the layers, as a prefill cache holds it, is folded
+        inside the call, so the caller makes no copy to drop it."""
         h, _ = self._seqs[slot]
-        n = k.shape[0]
-        pad = len(h.tiles) * cfg.block_size - n
-        if pad:
-            k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
-        kb = k.reshape(len(h.tiles), cfg.block_size, cfg.kv_heads, cfg.head_dim)
-        vb = v.reshape(len(h.tiles), cfg.block_size, cfg.kv_heads, cfg.head_dim)
-        idx = jnp.asarray(h.tiles, jnp.int32)
-        self.k = self.k.at[layer, idx].set(kb.astype(self.k.dtype))
-        self.v = self.v.at[layer, idx].set(vb.astype(self.v.dtype))
+        found = h.runs()
+        runs = np.zeros((len(h.tiles), 3), np.int32)
+        src = 0
+        for r, (first, length) in enumerate(found):
+            runs[r] = first, src, length
+            src += length
+        self.k, self.v = _write_pages(
+            self.k, self.v, runs, np.int32(len(found)), k, v
+        )
 
-    def write_token_kv(
-        self, slot: int, layer: int, k1: jax.Array, v1: jax.Array
-    ) -> None:
-        """Write one decoded token's K/V (kv_heads, head_dim)."""
-        cfg = self.cfg
+    def token_dest(self, slot: int) -> Tuple[int, int]:
+        """(page, offset) of the sequence's latest token: where a decode
+        step's ``write_token_kv`` lands it."""
         h, ntok = self._seqs[slot]
         pos = ntok - 1
-        block = h.tiles[pos // cfg.block_size]
-        off = pos % cfg.block_size
-        self.k = self.k.at[layer, block, off].set(k1.astype(self.k.dtype))
-        self.v = self.v.at[layer, block, off].set(v1.astype(self.v.dtype))
+        return h.tiles[pos // self.cfg.block_size], pos % self.cfg.block_size
+
+    def write_token_kv(
+        self, dests: Sequence[Tuple[int, int]], k: jax.Array, v: jax.Array
+    ) -> None:
+        """Write one decoded token's K/V per sequence, every layer,
+        ``(n_layers, B, kv_heads, head_dim)`` each, to the B ``(page,
+        offset)`` destinations (``token_dest``) in one in-place call."""
+        blocks = np.asarray([b for b, _ in dests], np.int32)
+        offs = np.asarray([o for _, o in dests], np.int32)
+        self.k, self.v = _write_tokens(self.k, self.v, blocks, offs, k, v)
 
     def occupancy(self) -> Dict[str, float]:
         """Point-in-time pool occupancy sample (all floats, JSON-friendly):
@@ -337,3 +337,61 @@ class PagedKVPool:
     def channel_occupancy(self) -> Dict[str, object]:
         """Per-channel used/free block counts (detail behind the balance)."""
         return self.pool.channel_occupancy()
+
+
+# The pool's writes: each takes the K and V pools donated and returns them
+# updated in place, so no copy of a pool outlives a write.  Compiled once per
+# shape: per batch size, per prompt length.
+#
+# Both are made of dynamic slices and updates, because on a TPU the layout
+# of a pool is the compiler's: a head dimension under 128 lanes (stablelm's
+# 64) puts the page axis in the lanes, and a scatter there turns both pools
+# row-major and back.  In that layout an update rewrites every tile of the
+# lane groups it touches, so a prompt is written one contiguous run of pages
+# at a time (a window of as many pages as the prompt has), not page by page:
+# PUMA's placement, which keeps a sequence's pages in few runs, keeps this
+# write short.
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_tokens(k_pool, v_pool, blocks, offs, k, v):
+    for i in range(k.shape[1]):
+        at = (0, blocks[i], offs[i], 0, 0)
+        k_pool = jax.lax.dynamic_update_slice(
+            k_pool, k[:, i, None, None].astype(k_pool.dtype), at)
+        v_pool = jax.lax.dynamic_update_slice(
+            v_pool, v[:, i, None, None].astype(v_pool.dtype), at)
+    return k_pool, v_pool
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_pages(k_pool, v_pool, runs, n_runs, k, v):
+    """``runs``: one row (first pool page, first prompt page, length) per
+    contiguous run of the prompt's pages, the first ``n_runs`` rows used."""
+    n_layers, nb, bs = k_pool.shape[:3]
+    n = runs.shape[0]
+    q = jnp.arange(n)[None, :, None, None, None]
+
+    def pages(x, pool):
+        # (L, n pages, bs, KV, hd), with n zero pages on either side
+        x = x.reshape((n_layers, -1) + pool.shape[3:]).astype(pool.dtype)
+        x = jnp.pad(x, ((0, 0), (n * bs, 2 * n * bs - x.shape[1]), (0, 0), (0, 0)))
+        return x.reshape((n_layers, 3 * n) + pool.shape[2:])
+
+    xk, xv = pages(k, k_pool), pages(v, v_pool)
+
+    def one_run(r, pools):
+        first, src, length = runs[r, 0], runs[r, 1], runs[r, 2]
+        at = jnp.minimum(first, nb - n)           # an n-page window holding the run
+        shift = first - at
+        inside = (q >= shift) & (q < shift + length)
+
+        def put(pool, x):
+            old = jax.lax.dynamic_slice_in_dim(pool, at, n, axis=1)
+            new = jax.lax.dynamic_slice_in_dim(x, n + src - shift, n, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(
+                pool, jnp.where(inside, new, old), at, axis=1)
+
+        return put(pools[0], xk), put(pools[1], xv)
+
+    return jax.lax.fori_loop(0, n_runs, one_run, (k_pool, v_pool))

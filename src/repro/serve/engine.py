@@ -6,13 +6,15 @@ Lifecycle per step:
      PUMA placement (worst-fit first allocation) assigns prompt blocks.
      Admission scans a bounded *lookahead window* of the queue, so one
      large head-of-line request cannot starve small requests behind it.
-  2. **prefill** — teacher-forced pass with a dense scratch cache, then the
-     per-layer K/V pages are scattered into the pool blocks (a bulk
-     RowClone-style block write).
+  2. **prefill** — teacher-forced pass with a dense scratch cache, then every
+     layer's K/V pages are written into the pool blocks in one in-place call
+     (a bulk RowClone-style block write).
   3. **decode** — one fused step for every live sequence via
-     ``paged_decode_step`` (block tables + seq_lens), greedy sampling.
-  4. **bookkeeping** — new-token K/V written to the PUMA-chosen block
-     (``extend`` keeps arena locality), finished sequences release blocks.
+     ``paged_decode_step`` (block tables + seq_lens), then the batch's
+     new-token K/V, every layer, written to the PUMA-chosen blocks in one
+     in-place call; greedy sampling.
+  4. **bookkeeping** — tokens appended (``extend`` keeps arena locality),
+     finished sequences release blocks.
 
 Hardened (degraded-mode) path — no request is ever silently dropped:
 
@@ -57,12 +59,16 @@ their only switch:
     bookkeeping (token append, release) is its self time;
   * ``serve.admit`` — the deadline sweep and the admission scan;
   * ``serve.prefill`` (``rid``, ``tokens``) — one request's jitted prefill,
-    its ``write_prompt_kv`` loop and first-token argmax (inside ``admit``);
+    its one ``write_prompt_kv`` call and first-token argmax (inside
+    ``admit``);
   * ``serve.decode_dispatch`` (``batch``) — block tables, ``seq_lens``,
     host-to-device inputs and the asynchronous call of the paged step;
-  * ``serve.sample`` — the host waiting for the step's argmax;
-  * ``serve.kv_writeback`` (``rid``) — one sequence's per-layer
-    ``write_token_kv`` calls;
+  * ``serve.kv_writeback`` (``rid``) — one sequence's share of the write:
+    the lookup of its token's page and offset.  The batch's one
+    asynchronous ``write_token_kv`` call follows these spans, in the
+    self time of ``serve.step``;
+  * ``serve.sample`` — the host waiting for the step's argmax (the device
+    runs the step, then the write, then the argmax);
   * ``serve.maintain`` — a compaction pass, only when one runs.
 """
 from __future__ import annotations
@@ -373,7 +379,6 @@ class ServeEngine:
         a fresh request (out empty) and a preempted one resuming
         (recompute-on-resume).  Returns False if the request had to be
         rejected (pathological: pool cannot host the sampled token)."""
-        cfg = self.cfg
         ctx = req.prompt + req.out[:-1]
         with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(ctx)):
             toks = jnp.asarray([ctx], jnp.int32)
@@ -385,8 +390,7 @@ class ServeEngine:
             self.tokens_prefilled += S
             # prompt KV lands in the recent ring (split cache, len_main == 0)
             k, v = cache["layers"]["recent"]            # (L, 1, S, KV, hd)
-            for li in range(cfg.n_layers):
-                self.pool.write_prompt_kv(req.slot, li, k[li, 0, :S], v[li, 0, :S])
+            self.pool.write_prompt_kv(req.slot, k, v)
             if self.trace is not None:
                 self.trace.on_prefill(
                     req.slot, req.rid, S, self.pool.tiles_of(req.slot)
@@ -510,23 +514,27 @@ class ServeEngine:
                 self.pool.k, self.pool.v, tbl, lens,
                 use_kernel=self.use_kernel,
             )
+        # 3) write every sequence's current-token KV into its PUMA-placed
+        #    page in one in-place call, while the whole batch is still live:
+        #    a sequence preempted below was written into pages it then frees,
+        #    which seq_lens mask until they are rewritten
+        dests = []
+        for slot in slots:
+            with TraceAnnotation("serve.kv_writeback", rid=self.live[slot].rid):
+                dests.append(self.pool.token_dest(slot))
+        self.pool.write_token_kv(dests, new_k, new_v)
         with TraceAnnotation("serve.sample"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
 
-        # 3) write current-token KV into PUMA-placed blocks, advance seqs
+        # 4) advance sequences
         for bi, slot in enumerate(slots):
             if slot not in self.live:
                 continue                    # preempted earlier this loop
             req = self.live[slot]
-            with TraceAnnotation("serve.kv_writeback", rid=req.rid):
-                for li in range(cfg.n_layers):
-                    self.pool.write_token_kv(slot, li, new_k[li, bi], new_v[li, bi])
             if self.trace is not None:
                 # one block-granular write per decoded token (all layers'
                 # planes of that block count as the one row touch)
-                self._step_writes.append(
-                    (slot, self.pool.block_of_token(slot))
-                )
+                self._step_writes.append((slot, dests[bi][0]))
             tok = int(nxt[bi])
             self.tokens_decoded += 1
             finished = (

@@ -48,7 +48,8 @@ def paged_decode_step(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (logits (B, V), new_k (L, B, KV, hd), new_v (L, B, KV, hd)).
 
-    The caller scatters new_k/new_v into pool blocks (host-side PUMA
+    The caller writes new_k/new_v into pool blocks, every layer and
+    sequence in one ``PagedKVPool.write_token_kv`` call (host-side PUMA
     bookkeeping decides *which* blocks — that's the paper's policy layer).
     Attention masks to ``seq_lens`` which already counts the current token,
     whose K/V is injected via a one-slot overlay so the kernel sees it

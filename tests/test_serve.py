@@ -112,3 +112,51 @@ def test_fork_shares_prefix(model_and_params):
     done = eng.run()
     outs = {r.rid: r.out for r in done}
     assert outs[0][-3:] == outs[1][-3:]
+
+
+def _pressured_run(model, params):
+    """Six requests through a pool of 8 pages of 8 tokens with four slots,
+    in bf16: pages fill up, so the engine preempts and resumes."""
+    cfg = model.cfg
+    eng = ServeEngine(model, params, _pool_cfg(
+        cfg, num_blocks=8, blocks_per_arena=4, max_seqs=4, dtype="bfloat16"))
+    rng = np.random.default_rng(3)
+    for i in range(6):
+        n = int(rng.integers(3, 21))
+        eng.submit(Request(rid=i, prompt=[int(t) for t in rng.integers(0, cfg.vocab_size, n)],
+                           max_new=10))
+    return eng, {r.rid: r.out for r in eng.run()}
+
+
+#: served by the engine as it was when each layer of each sequence was
+#: written by its own eager call, on the CPU
+RECORDED = {
+    0: [729, 281, 750, 151, 196, 1735, 25, 1585, 11, 1209],
+    1: [946, 108, 237, 1616, 908, 725, 1801, 411, 1645, 1096],
+    2: [143, 750, 883, 914, 387, 1566, 481, 2037, 208, 1261],
+    3: [1280, 698, 1656, 702, 650, 1631, 302, 1853, 80, 1322],
+    4: [1149, 1815, 815, 321, 2035, 883, 355, 557, 770, 949],
+    5: [874, 55, 782, 1220, 770, 1661, 1772, 511, 1553, 1928],
+}
+
+
+def test_served_tokens_match_the_per_layer_writes(model_and_params):
+    eng, outs = _pressured_run(*model_and_params)
+    assert eng.preemptions == 3
+    assert outs == RECORDED
+
+
+def test_one_pool_write_per_decode_step_and_per_prefill(model_and_params, monkeypatch):
+    from repro.core.kv_pool import PagedKVPool
+
+    calls = {"write_token_kv": 0, "write_prompt_kv": 0}
+    for name in calls:
+        def counted(self, *a, _f=getattr(PagedKVPool, name), _n=name):
+            calls[_n] += 1
+            return _f(self, *a)
+        monkeypatch.setattr(PagedKVPool, name, counted)
+    eng, outs = _pressured_run(*model_and_params)
+    assert len(outs) == 6 and eng.preemptions > 0
+    assert calls["write_token_kv"] == eng.steps
+    # every admission prefills once; a preempted request prefills again
+    assert calls["write_prompt_kv"] == 6 + eng.preemptions

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_config
+from repro.core import kv_pool
 from repro.kernels.paged_attention import kernel as paged_k
 from repro.kernels.pud_bulk import kernel as pud_k
 from repro.launch.serve import pool_config
@@ -99,3 +100,29 @@ def test_paged_decode_step_compiles_at_published_width(spec, monkeypatch):
         jax.clear_caches()       # drop traces made while the backend was faked
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("write", ["token", "prompt"])
+def test_pool_writes_compile_in_place(spec, write):
+    # stablelm-1.6b's pool at 1088 pages: its head dimension of 64 puts the
+    # page axis in the lanes, where a scatter would turn the pools row-major
+    shape = (24, 1088, 16, 32, 64)
+    pool = spec(shape, jnp.bfloat16)
+    if write == "token":
+        B = 8
+        kv = spec((24, B, 32, 64), jnp.bfloat16)
+        lowered = kv_pool._write_tokens.lower(
+            pool, pool, spec((B,), jnp.int32), spec((B,), jnp.int32), kv, kv)
+    else:
+        S = 2048
+        kv = spec((24, 1, S, 32, 64), jnp.bfloat16)
+        lowered = kv_pool._write_pages.lower(
+            pool, pool, spec((S // 16, 3), jnp.int32), spec((), jnp.int32), kv, kv)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 24 * 1088 * 16 * 32 * 64 * 2
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes       # both pools donated
+    copies = [l for l in compiled.as_text().splitlines() if " copy(" in l]
+    assert not [l for l in copies if "bf16[24,1088,16,32,64]" in l]
+    if write == "token":
+        assert mem.temp_size_in_bytes < pool_bytes // 100
