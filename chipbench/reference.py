@@ -1,12 +1,16 @@
-"""Plain reference forward pass, and the check that decides ``correct``.
+"""Plain reference, shared by every family, and the check that decides
+``correct``.
 
 Straightforward ``jax.numpy`` in float32 at ``Precision.HIGHEST``: no
-kernel, no cache, no batching.  It reads the configuration file's sizes
-(``spec_of``) and the benchmark's own weights (``weights.py``), and
-imports nothing of the program.  One prompt with its served tokens runs
-through the layers one at a time, padded at the end to one bucket length
-(causal attention leaves earlier positions untouched), so one compile
-serves every request of a cell.
+kernel, no cache, no batching.  Here are the pieces every family's
+reference uses (matmul, fp8 fake-quantization, norms, rotary) and the
+comparison; each family's layers and layer loop are its
+``forward_logits`` in ``families/<family>.py``.  It reads the
+configuration file's sizes (``spec_of``) and the benchmark's own weights
+(``weights.py``), and imports nothing of the program.  One prompt with its
+served tokens runs through the layers one at a time, padded at the end to
+one bucket length (causal attention leaves earlier positions untouched),
+so one compile serves every request of a cell.
 
 The number compared is the widest gap, over every served token, by which
 the served token's reference logit lies below the reference's best logit
@@ -17,8 +21,7 @@ bfloat16) is read the same way, for the token it puts first.
 """
 from __future__ import annotations
 
-import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,50 +72,6 @@ def _rope(x, spec):
     return jnp.concatenate([rot, x[..., rd:]], axis=-1)
 
 
-@functools.partial(jax.jit, static_argnames=("spec_items", "fp8"))
-def _layer(x, layers, li, *, spec_items, fp8):
-    spec = dict(spec_items)
-    lw = jax.tree.map(lambda a: a[li], layers)
-    T = x.shape[0]
-    H, KV, hd = spec["heads"], spec["kv_heads"], spec["head_dim"]
-    h = _norm(x, lw["norm1"], spec)
-    q = _rope(_mm("td,dhk->thk", h, lw["wq"], fp8), spec)
-    k = _rope(_mm("td,dhk->thk", h, lw["wk"], fp8), spec)
-    v = _mm("td,dhk->thk", h, lw["wv"], fp8)
-    k = jnp.repeat(k, H // KV, axis=1)
-    v = jnp.repeat(v, H // KV, axis=1)
-    s = _mm("qhd,khd->hqk", q, k, fp8) * hd ** -0.5
-    causal = jnp.arange(T)[None, :, None] >= jnp.arange(T)[None, None, :]
-    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-    o = _mm("hqk,khd->qhd", p, v, fp8)
-    x = x + _mm("thk,hkd->td", o, lw["wo"], fp8)
-    h = _norm(x, lw["norm2"], spec)
-    g = _mm("td,df->tf", h, lw["w_gate"], fp8)
-    u = _mm("td,df->tf", h, lw["w_up"], fp8)
-    return x + _mm("tf,fd->td", jax.nn.silu(g) * u, lw["w_down"], fp8)
-
-
-@functools.partial(jax.jit, static_argnames=("spec_items", "span", "fp8"))
-def _logits(x, final_norm, head, start, *, spec_items, span, fp8):
-    """Logits over the published vocabulary at positions [start, start+span)."""
-    spec = dict(spec_items)
-    xs = jax.lax.dynamic_slice_in_dim(x, start, span, axis=0)
-    return _mm("td,dv->tv", _norm(xs, final_norm, spec), head[:, :spec["vocab"]], fp8)
-
-
-def forward_logits(w: Dict, spec: Dict, ids: Sequence[int], start: int, span: int,
-                   bucket: int, fp8: bool = False) -> jax.Array:
-    """Reference logits (span, vocab) of ``ids`` at positions start.., with
-    the sequence padded to ``bucket`` tokens."""
-    items = tuple(sorted(spec.items()))
-    toks = np.zeros((bucket,), np.int32)
-    toks[:len(ids)] = ids
-    x = w["embed"][jnp.asarray(toks)]
-    for li in range(spec["layers"]):
-        x = _layer(x, w["layers"], li, spec_items=items, fp8=fp8)
-    return _logits(x, w["final_norm"], w["head"], start, spec_items=items, span=span, fp8=fp8)
-
-
 @jax.jit
 def _gaps(ref, served):
     """Per position: best reference logit minus the reference logit of
@@ -120,11 +79,13 @@ def _gaps(ref, served):
     return ref.max(-1) - jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
 
 
-def served_gaps(w: Dict, spec: Dict, prompt: List[int], served: List[int], span: int,
-                bucket: int, control: bool = False) -> Tuple[np.ndarray, np.ndarray | None]:
-    """Gaps of every served token under the reference; with ``control``,
-    also the gaps of the tokens the fp8 control puts first at the same
-    positions.  Served ids outside the published vocabulary get +inf."""
+def served_gaps(forward_logits: Callable, w: Dict, spec: Dict, prompt: List[int],
+                served: List[int], span: int, bucket: int,
+                control: bool = False) -> Tuple[np.ndarray, np.ndarray | None]:
+    """Gaps of every served token under the reference logits of the
+    family's ``forward_logits``; with ``control``, also the gaps of the
+    tokens the fp8 control puts first at the same positions.  Served ids
+    outside the published vocabulary get +inf."""
     n = len(served)
     ids = list(prompt) + list(served[:-1])
     start = len(prompt) - 1

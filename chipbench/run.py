@@ -4,10 +4,13 @@
 
 Run from the root of a checkout on a machine with the chips the cell asks
 for.  The cell names a configuration file (``chipbench/configs/``) and a
-traffic mix (``chipbench/traffic/``); metrics are readers in
+traffic mix (``chipbench/traffic/``); the configuration file names its
+family, whose module ``chipbench/families/<family>.py`` holds everything
+model-specific: the program's config and pool, the weight layout, the
+reference's layers and the FLOP and byte counts.  Metrics are readers in
 ``chipbench/metrics/<name>.py``, limits of the correctness check are in
 ``chipbench/limits/<cell>.json``.  Nothing here knows a cell, a
-configuration, a mix or a metric by name.
+configuration, a family, a mix or a metric by name.
 
 Set-up makes the weights from the seed on the device, builds the
 program's ``ServeEngine`` over a pool sized by the mix, fills the batch
@@ -27,7 +30,6 @@ import time
 T_START = time.time()
 
 import argparse  # noqa: E402
-import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -72,12 +74,32 @@ def applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
-def load_reader(bench_dir: Path, name: str):
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench_dir: Path, name: str):
+    return load_module(bench_dir / "metrics" / f"{name}.py", f"chipbench_metric_{name}").read
+
+
+def load_family(bench_dir: Path, cfg_file: dict, cfg_path: Path):
+    """The module of the family that the configuration file names."""
+    if "family" not in cfg_file:
+        raise SystemExit(f"chipbench: {cfg_path} names no \"family\", so no module "
+                         f"{bench_dir / 'families'}/<family>.py can be looked for")
+    path = bench_dir / "families" / f"{cfg_file['family']}.py"
+    if not path.is_file():
+        raise SystemExit(f"chipbench: {cfg_path} names family {cfg_file['family']!r}, "
+                         f"and there is no {path}")
+    return load_module(path, f"chipbench_family_{cfg_file['family']}")
+
+
+def pool_arrays(pool) -> list:
+    """Every device array the pool holds (K and V pages, and any state)."""
+    return [a for a in vars(pool).values() if isinstance(a, jax.Array)]
 
 
 def require_chips(n: int):
@@ -87,23 +109,6 @@ def require_chips(n: int):
         raise SystemExit(f"chipbench: needs {n} TPU chip(s), JAX found "
                          f"{len(devs)} {devs[0].platform!r} device(s)")
     return devs[0]
-
-
-def program_config(cfg_file: dict, spec: dict):
-    """The program's ModelConfig for this configuration file, checked
-    against the reference's sizes ``spec``."""
-    from repro.configs.registry import get_config
-
-    pcfg = dataclasses.replace(get_config(cfg_file["arch"]), **cfg_file["overrides"])
-    have = {"layers": pcfg.n_layers, "d_model": pcfg.d_model, "heads": pcfg.n_heads,
-            "kv_heads": pcfg.n_kv_heads, "head_dim": pcfg.hd, "d_ff": pcfg.d_ff,
-            "vocab": pcfg.vocab_size, "norm": pcfg.norm, "compute_dtype": pcfg.dtype,
-            "kv_dtype": pcfg.kv_cache_dtype, "rope_theta": pcfg.rope_theta,
-            "rotary_dims": pcfg.hd // 2 if pcfg.rope == "rope2d" else pcfg.hd}
-    bad = {k: (v, spec.get(k)) for k, v in have.items() if spec.get(k) != v}
-    if bad or pcfg.activation != "swiglu" or pcfg.family != "dense":
-        raise SystemExit(f"chipbench: program config departs from the file: {bad}")
-    return pcfg
 
 
 class Loop:
@@ -167,7 +172,9 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     bench = load_benchmark(root)
     bench_dir = root / bench["paths"][0]
     cell, cfg_entry = find_cell(bench, workload)
-    cfg_file = json.loads((root / cfg_entry["file"]).read_text())
+    cfg_path = root / cfg_entry["file"]
+    cfg_file = json.loads(cfg_path.read_text())
+    family = load_family(bench_dir, cfg_file, cfg_path)
     mix = traffic.load_mix(bench_dir / "traffic" / f"{cell['traffic']}.json")
     limits = json.loads((bench_dir / "limits" / f"{workload}.json").read_text())
     e2e = [m for m in bench["end_to_end"] if applies(m, workload)]
@@ -177,7 +184,6 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     dev = require_chips(cell["chips"]) if require_chip else jax.devices()[0]
     if str(root / "src") not in sys.path:
         sys.path.insert(0, str(root / "src"))
-    from repro.core.kv_pool import KVPoolConfig
     from repro.launch.serve import use_compile_cache
     from repro.models.transformer import LM
     from repro.serve.engine import Request, ServeEngine
@@ -194,22 +200,19 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
 
     phases = {"start": time.time() - t_start}
     spec = reference.spec_of(cfg_file)
-    pcfg = program_config(cfg_file, spec)
+    pcfg = family.program_config(cfg_file, spec)
     model = LM(pcfg, attn_impl="naive", remat=None)
     padded_vocab = jax.eval_shape(model.init, jax.random.key(0))["embed"]["tok"].shape[0]
-    w = jax.block_until_ready(
-        weights.make_weights(spec, padded_vocab, traffic.seed_state(seed, 1)))
-    params = weights.program_params(w, model)
+    w = jax.block_until_ready(weights.make_weights(
+        family.weight_shapes(spec, padded_vocab), family.FAN_IN, spec["vocab"],
+        traffic.seed_state(seed, 1)))
+    params = family.program_params(w, model)
     phases["weights"] = time.time() - t_start
-    num_blocks, per_seq = traffic.pool_sizing(mix)
-    pool_cfg = KVPoolConfig(
-        num_blocks=num_blocks, block_size=mix["block_size"], kv_heads=pcfg.n_kv_heads,
-        head_dim=pcfg.hd, n_layers=pcfg.n_layers, max_seqs=mix["sessions"],
-        max_blocks_per_seq=per_seq, blocks_per_arena=mix["blocks_per_arena"],
-        dtype=pcfg.kv_cache_dtype)
+    pool_cfg = family.pool_config(pcfg, mix)
     eng = ServeEngine(model, params, pool_cfg)
-    log(f"model={pcfg.name} layers={pcfg.n_layers} pool pages={num_blocks}x{mix['block_size']} "
-        f"max_pages_per_seq={per_seq} bytes_per_pool={eng.pool.k.nbytes} "
+    log(f"model={pcfg.name} layers={pcfg.n_layers} pool pages={pool_cfg.num_blocks}x"
+        f"{pool_cfg.block_size} max_pages_per_seq={pool_cfg.max_blocks_per_seq} "
+        f"bytes_per_pool={sum(a.nbytes for a in pool_arrays(eng.pool))} "
         f"sessions={mix['sessions']} kernels={eng.use_kernel}")
 
     phases["engine"] = time.time() - t_start
@@ -229,7 +232,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         for _ in range(2):
             eng.step()
             fill_log.append(loop.harvest(time.perf_counter()))
-        jax.block_until_ready((eng.pool.k, eng.pool.v))
+        jax.block_until_ready(pool_arrays(eng.pool))
     n_compiles_setup = len(compiles)
     phases["fill"] = time.time() - t_start
     log("set-up seconds since start, by phase end: "
@@ -251,7 +254,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
             if window.closes(t, t0, seconds):
                 break
         with span(trace, "chipbench.drain"):
-            jax.block_until_ready((eng.pool.k, eng.pool.v))
+            jax.block_until_ready(pool_arrays(eng.pool))
     t1 = time.perf_counter()
     compiles_in_window = sum(1 for c in compiles if t0 <= c <= t1)
     reduced = None
@@ -278,7 +281,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     gc.collect()
     token_times = {rid: rec["times"] for rid, rec in loop.records.items()}
     run = {
-        "workload": workload, "seed": seed, "spec": spec, "mix": mix,
+        "workload": workload, "seed": seed, "spec": spec, "family": family, "mix": mix,
         "setup_s": setup_s, "window": {"t0": t0, "t1": t1, "seconds": seconds},
         "token_times": token_times, "steps": steps_log, "fill": fill_log,
         "compiles_in_window": compiles_in_window, "peak_bytes": peak,
@@ -292,7 +295,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
             metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
 
     t_check = time.perf_counter()
-    checks, readings = check_served(w, spec, mix, loop, t0, t1, seed, limits, control)
+    checks, readings = check_served(family.forward_logits, w, spec, mix, loop, t0, t1, seed,
+                                    limits, control)
     log(f"reference check over {readings['tokens']} served tokens took "
         f"{time.perf_counter() - t_check:.3f} s")
     correct = all(c["ok"] for c in checks.values())
@@ -313,10 +317,11 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     return result
 
 
-def check_served(w, spec, mix, loop: Loop, t0: float, t1: float, seed: int,
+def check_served(forward_logits, w, spec, mix, loop: Loop, t0: float, t1: float, seed: int,
                  limits: dict, control: bool = False) -> tuple[dict, dict]:
-    """Reference check of a seeded sample of the requests that served
-    tokens in the window, the one with the most served tokens among them.
+    """Reference check, by the family's ``forward_logits``, of a seeded
+    sample of the requests that served tokens in the window, the one with
+    the most served tokens among them.
     The checks compare the program's served tokens or, with ``control``,
     the tokens the fp8 control puts first at the same positions; the
     readings hold both sides' widest gaps."""
@@ -333,8 +338,8 @@ def check_served(w, spec, mix, loop: Loop, t0: float, t1: float, seed: int,
         bucket = -(-(max(mix["prompt_tokens"]["values"]) + span_) // 128) * 128
         for rid in sample:
             req = loop.records[rid]["req"]
-            gaps, ctrl = reference.served_gaps(w, spec, req.prompt, req.out, span_, bucket,
-                                               control=control)
+            gaps, ctrl = reference.served_gaps(forward_logits, w, spec, req.prompt, req.out,
+                                               span_, bucket, control=control)
             gap_max = max(gap_max, float(gaps.max()))
             if control:
                 ctrl_max = max(ctrl_max, float(ctrl.max()))

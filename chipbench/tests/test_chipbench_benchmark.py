@@ -27,6 +27,7 @@ def test_every_named_file_exists():
     for c in B["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert c["reduced"] == cfg["reduced"]
+        assert (ROOT / "chipbench" / "families" / f"{cfg['family']}.py").exists()
         assert not any(k.endswith(("_dim", "_rank", "_size")) for k in c["reduced"])
     for w in B["workloads"]:
         assert w["chips"] == 1 and len(w["why"]) <= 200

@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 
 from chipbench import window
-from chipbench.metrics import itl_p95_ms, output_tokens_per_s
+from chipbench.metrics import itl_p99_ms, output_tokens_per_s
 
 
 def simulate(step_s, seconds, batch):
@@ -33,15 +33,15 @@ def test_tokens_per_s_counts_whole_steps_over_elapsed_time():
     assert output_tokens_per_s.read(run) == pytest.approx(4 * 5 / 12.0)
 
 
-def test_p95_is_over_every_gap_not_a_median_of_chunks():
+def test_p99_is_over_every_gap_not_a_median_of_chunks():
     times = {0: [1.0, 2.0, 3.0, 13.0], 1: [1.0, 1.5, 2.0, 2.5, 3.0, 3.5]}
     run = {"window": {"t0": 0.0, "t1": 20.0}, "token_times": times}
     gaps = [1.0, 1.0, 10.0] + [0.5] * 5
-    assert itl_p95_ms.read(run) == pytest.approx(np.percentile(gaps, 95) * 1e3)
+    assert itl_p99_ms.read(run) == pytest.approx(np.percentile(gaps, 99) * 1e3)
 
 
 def test_gaps_need_both_tokens_inside_the_window():
     times = [[-1.0, 0.5, 1.0, 9.0, 12.0]]
     assert window.gaps_in_window(times, 0.0, 10.0) == [0.5, 8.0]
     assert window.tokens_in_window(times, 0.0, 10.0) == 3
-    assert window.p95([]) is None
+    assert window.percentile([], 99) is None

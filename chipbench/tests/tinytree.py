@@ -1,6 +1,7 @@
 """A checkout-shaped tree with a tiny configuration, for CPU runs of the
 harness: BENCHMARK.json, the benchmark's directory with a config, a mix,
-limits and the real metric readers, and the program's ``src``."""
+limits, the real metric readers and family modules, and the program's
+``src``."""
 import json
 import shutil
 from pathlib import Path
@@ -18,8 +19,9 @@ def build(tmp: Path, limit: float, extra_metric: str | None = None) -> Path:
     d = tmp / "bench"
     for sub in ("configs", "traffic", "limits"):
         (d / sub).mkdir(parents=True)
-    shutil.copytree(ROOT / "chipbench" / "metrics", d / "metrics",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for sub in ("metrics", "families"):
+        shutil.copytree(ROOT / "chipbench" / sub, d / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "src").symlink_to(ROOT / "src")
     cfg = json.loads((ROOT / "chipbench" / "configs" / "stablelm_1_6b.json").read_text())
     cfg.update({"num_hidden_layers": TINY["n_layers"], "hidden_size": TINY["d_model"],

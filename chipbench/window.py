@@ -32,7 +32,7 @@ def gaps_in_window(token_times: Iterable[List[float]], t0: float, t1: float) -> 
     return out
 
 
-def p95(values: List[float]) -> float | None:
-    """95th percentile (linear interpolation); None with no samples."""
-    return float(np.percentile(np.asarray(values, float), 95)) if values else None
+def percentile(values: List[float], q: float) -> float | None:
+    """The ``q``-th percentile (linear interpolation); None with no samples."""
+    return float(np.percentile(np.asarray(values, float), q)) if values else None
 
