@@ -25,7 +25,7 @@ class ModelConfig:
     vocab_size: int
     head_dim: Optional[int] = None          # default d_model // n_heads
     # positional encoding
-    rope: str = "rope"                      # rope | rope2d | mrope | none
+    rope: str = "rope"                      # rope | rope_half | rope2d | mrope | none
     rope_theta: float = 10000.0
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)  # t/h/w split of half-dims
     # MoE
@@ -37,14 +37,22 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_head_dim: int = 64
-    # hybrid (zamba2-style): one shared attention block applied every k layers
-    attn_every: int = 0
+    ssm_ngroups: int = 1                    # mamba2: B and C groups over the heads
+    ssm_conv_bias: bool = False
+    # hybrid (zamba2): shared attention blocks, used in turn (use u takes
+    # block u mod num_mem_blocks) before the Mamba2 layers hybrid_layer_ids
+    # names; each use has its own rank-adapter_rank LoRA on the MLP's
+    # gate_up and its own d_model x d_model linear into the Mamba input
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     # encoder-decoder
     enc_layers: int = 0                     # >0 => enc-dec; n_layers = decoder
     cross_attention: bool = False
     # misc
     activation: str = "swiglu"              # swiglu | gelu
     norm: str = "rmsnorm"                   # rmsnorm | layernorm
+    norm_eps: float = 1e-6                  # read by the hybrid family's norms
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     kv_cache_dtype: str = "bfloat16"   # "float8_e4m3fn" = quantized KV pages
@@ -55,6 +63,17 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def hybrid_uses(self) -> Tuple[int, ...]:
+        """The Mamba2 layers a shared block runs before, within the depth."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.n_layers)
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep attention KV: a hybrid's shared-block uses,
+        every layer of the other attention families."""
+        return len(self.hybrid_uses) if self.family == "hybrid" else self.n_layers
 
     @property
     def is_encdec(self) -> bool:
@@ -92,10 +111,15 @@ class ModelConfig:
         if self.family == "hybrid":
             d_in = self.ssm_expand * d
             nh = d_in // self.ssm_head_dim
-            mamba = d * (2 * d_in + 2 * nh) + d_in * d + nh * self.ssm_state * 0
-            total = L * (mamba + 2 * d) + emb
-            # shared attention block (counted once - weights shared)
-            total += attn + 3 * d * f
+            conv_dim = d_in + 2 * self.ssm_ngroups * self.ssm_state
+            mamba = (d * (d_in + conv_dim + nh) + self.ssm_conv * conv_dim
+                     + conv_dim * self.ssm_conv_bias + 3 * nh + d_in + d_in * d + d)
+            # a shared block reads the 2*d concat of residual and embedding
+            shared = (2 * d + 2 * d * H * hd + 2 * 2 * d * KV * hd + H * hd * d + d
+                      + 3 * d * f)
+            per_use = self.adapter_rank * (d + 2 * f) + d * d
+            total = (L * mamba + self.num_mem_blocks * shared
+                     + len(self.hybrid_uses) * per_use + emb + d)
         return int(total)
 
     def n_active_params(self) -> int:
@@ -114,7 +138,7 @@ class ModelConfig:
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=max(2, min(3, self.n_layers)),
+            n_layers=5 if self.hybrid_layer_ids else max(2, min(3, self.n_layers)),
             d_model=128,
             n_heads=4,
             n_kv_heads=max(1, min(self.n_kv_heads, 2)) if self.n_kv_heads < self.n_heads else 4,
@@ -126,7 +150,8 @@ class ModelConfig:
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=16 if self.ssm_state else 64,
             enc_layers=2 if self.enc_layers else 0,
-            attn_every=2 if self.attn_every else 0,
+            hybrid_layer_ids=(1, 2, 4) if self.hybrid_layer_ids else (),
+            adapter_rank=min(self.adapter_rank, 8),
             mrope_sections=(4, 6, 6),
             dtype="float32",
             kv_cache_dtype="float32",

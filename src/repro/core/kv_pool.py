@@ -11,6 +11,15 @@ goes worst-fit, subsequent blocks of the same request go ``extend`` (same
 arena, adjacent slot when possible), and the V handle is ``alloc_align``-ed
 against the K handle so K/V block *k* live at mirrored offsets.
 
+A hybrid model's recurrent layers keep a fixed-size state per sequence
+instead of pages: with ``state_layers`` > 0 the pool also holds *state
+slots*, ``conv`` (max_seqs, state_layers, conv_width) and ``ssd``
+(max_seqs, state_layers, ssd_heads, ssd_width), float32: a slot's state
+is one contiguous slab, with a minor dimension that fills whole 128-lane
+tiles.  A sequence's state slot is its sequence slot, so admission, which
+hands out a free sequence slot, is also the state's accounting; the
+prefill overwrites the whole slot, so admit zeroes nothing.
+
 The device side keeps everything as jnp arrays plus an int32 *block table*
 (max_seqs, max_blocks) — the TPU-idiomatic replacement for the paper's
 re-mmap (see DESIGN.md §2).  `paged_attention` consumes the table; its fast
@@ -33,7 +42,16 @@ if TYPE_CHECKING:
     from repro.robustness.faults import FaultInjector
     from repro.robustness.journal import Journal
 
-__all__ = ["KVPoolConfig", "PagedKVPool"]
+__all__ = ["KVPoolConfig", "PagedKVPool", "lane_dim"]
+
+
+def lane_dim(n: int) -> int:
+    """``n`` rounded up to whole 128-lane tiles.  A hybrid's pool pads its
+    head dimension so: a v5e then keeps the pool row-major, where a head
+    dimension off the tiles (224) has the page axis put in the lanes, and the
+    paged kernel, which needs row-major pages, would copy the pool every
+    step.  The padded lanes hold zeros."""
+    return -(-n // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,6 +67,11 @@ class KVPoolConfig:
     n_channels: int = 1             # memory channels the arenas stripe over
     policy: str = "puma"
     dtype: str = "bfloat16"
+    # per-sequence state slots of a hybrid's recurrent layers (0: none)
+    state_layers: int = 0
+    conv_width: int = 0             # (K-1) * conv_dim: the conv inputs kept
+    ssd_heads: int = 0
+    ssd_width: int = 0              # head_dim * state: one head's SSD state
 
     @property
     def n_arenas(self) -> int:
@@ -85,6 +108,11 @@ class PagedKVPool:
         shape = (cfg.n_layers, cfg.num_blocks, cfg.block_size, cfg.kv_heads, cfg.head_dim)
         self.k = jnp.zeros(shape, dt)
         self.v = jnp.zeros(shape, dt)
+        self.conv = self.ssd = None
+        if cfg.state_layers:
+            self.conv = jnp.zeros((cfg.max_seqs, cfg.state_layers, cfg.conv_width), jnp.float32)
+            self.ssd = jnp.zeros(
+                (cfg.max_seqs, cfg.state_layers, cfg.ssd_heads, cfg.ssd_width), jnp.float32)
         # seq slot -> (k_handle, token_count)
         self._seqs: Dict[int, Tuple[TileHandle, int]] = {}
         self._free_slots = list(range(cfg.max_seqs))
@@ -153,6 +181,8 @@ class PagedKVPool:
             self.k = pool_block_copy(kflat, src_all, dst_all, use_kernel=use_kernel).reshape(self.k.shape)
             self.v = pool_block_copy(vflat, src_all, dst_all, use_kernel=use_kernel).reshape(self.v.shape)
         new_slot = self._free_slots.pop(0)
+        if copy_data and self.conv is not None:
+            self.write_token_states([new_slot], self.conv[slot][:, None], self.ssd[slot][:, None])
         self._seqs[new_slot] = (h, ntok)
         if self.journal is not None:
             self.journal.append("kv_fork", slot=new_slot, hid=h.hid, ntok=ntok)
@@ -299,6 +329,21 @@ class PagedKVPool:
         offs = np.asarray([o for _, o in dests], np.int32)
         self.k, self.v = _write_tokens(self.k, self.v, blocks, offs, k, v)
 
+    def write_prompt_states(self, slot: int, conv: jax.Array, ssd: jax.Array) -> None:
+        """Write a prompt's final state of every recurrent layer into the
+        sequence's slot in one in-place call: ``conv`` (layers, 1, K-1,
+        conv_dim) and ``ssd`` (layers, 1, H, P, N), as a prefill cache
+        holds them."""
+        self.conv, self.ssd = _write_states(
+            self.conv, self.ssd, np.asarray([slot], np.int32), conv, ssd)
+
+    def write_token_states(self, slots: Sequence[int], conv: jax.Array, ssd: jax.Array) -> None:
+        """Write a decode step's new states, ``conv`` (layers, B,
+        conv_width) and ``ssd`` (layers, B, ssd_heads, ssd_width), into
+        the B sequences' slots in one in-place call."""
+        self.conv, self.ssd = _write_states(
+            self.conv, self.ssd, np.asarray(slots, np.int32), conv, ssd)
+
     def occupancy(self) -> Dict[str, float]:
         """Point-in-time pool occupancy sample (all floats, JSON-friendly):
         tile counts, used fraction, and sequence-slot pressure.  The serving
@@ -365,6 +410,22 @@ def _write_tokens(k_pool, v_pool, blocks, offs, k, v):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_states(conv_pool, ssd_pool, slots, conv, ssd):
+    """Sequence i's state, every layer, into slot ``slots[i]``: one
+    dynamic update of a contiguous slab per sequence (the slots are
+    distinct).  ``conv``/``ssd`` lead with (layers, B) and hold a slot's
+    elements in any shape."""
+    conv, ssd = jnp.swapaxes(conv, 0, 1), jnp.swapaxes(ssd, 0, 1)
+    B = conv.shape[0]
+    conv = conv.reshape((B, 1) + conv_pool.shape[1:]).astype(conv_pool.dtype)
+    ssd = ssd.reshape((B, 1) + ssd_pool.shape[1:]).astype(ssd_pool.dtype)
+    for i in range(B):
+        conv_pool = jax.lax.dynamic_update_slice_in_dim(conv_pool, conv[i], slots[i], axis=0)
+        ssd_pool = jax.lax.dynamic_update_slice_in_dim(ssd_pool, ssd[i], slots[i], axis=0)
+    return conv_pool, ssd_pool
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _write_pages(k_pool, v_pool, runs, n_runs, k, v):
     """``runs``: one row (first pool page, first prompt page, length) per
     contiguous run of the prompt's pages, the first ``n_runs`` rows used."""
@@ -373,9 +434,11 @@ def _write_pages(k_pool, v_pool, runs, n_runs, k, v):
     q = jnp.arange(n)[None, :, None, None, None]
 
     def pages(x, pool):
-        # (L, n pages, bs, KV, hd), with n zero pages on either side
-        x = x.reshape((n_layers, -1) + pool.shape[3:]).astype(pool.dtype)
-        x = jnp.pad(x, ((0, 0), (n * bs, 2 * n * bs - x.shape[1]), (0, 0), (0, 0)))
+        # (L, n pages, bs, KV, hd), with n zero pages on either side and
+        # zero lanes up to a pool head dimension padded to whole lane tiles
+        x = x.reshape((n_layers, -1) + x.shape[-2:]).astype(pool.dtype)
+        x = jnp.pad(x, ((0, 0), (n * bs, 2 * n * bs - x.shape[1]), (0, 0),
+                        (0, pool.shape[-1] - x.shape[-1])))
         return x.reshape((n_layers, 3 * n) + pool.shape[2:])
 
     xk, xv = pages(k, k_pool), pages(v, v_pool)

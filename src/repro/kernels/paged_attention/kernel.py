@@ -14,6 +14,12 @@ head count.  Queries come in group-major, ``(group, Hkv, D)``, so that
 each page token's ``(Hkv, D)`` K/V tile lines up with every query group
 by broadcasting over leading dims only.  Scores are lane reductions on the
 VPU: a decode step has one query row per head, too little to fill the MXU.
+
+Called with ``layer``, the pools hold every layer, ``(L, num_blocks, ...)``,
+and the kernel reads that layer's pages in place (the layer is one more
+scalar-prefetched index, so no slice of the pool is made) and also returns
+the log-sum-exp of each head's scores, which a caller needs to merge a
+token the pool does not hold yet.
 """
 from __future__ import annotations
 
@@ -66,17 +72,34 @@ def _paged_kernel(
         o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+def _paged_kernel_lse(tbl_ref, lens_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                      m_scr, l_scr, acc_scr, *, scale, block_size, n_blocks):
+    _paged_kernel(tbl_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                  scale=scale, block_size=block_size, n_blocks=n_blocks)
+
+    @pl.when(pl.program_id(1) == n_blocks - 1)
+    def _lse():
+        l = l_scr[...]
+        lse_ref[0] = jnp.where(l == 0.0, NEG_INF, m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l)))
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention(
     q: jax.Array,            # (B, Hkv, group, D)
-    k_pool: jax.Array,       # (num_blocks, block_size, Hkv, D)
+    k_pool: jax.Array,       # (num_blocks, block_size, Hkv, D), or (L, ...) with layer
     v_pool: jax.Array,
     block_tables: jax.Array,  # (B, max_blocks) int32, -1 padded
     seq_lens: jax.Array,      # (B,) int32
+    layer: jax.Array | None = None,   # (1,) int32
     *,
     scale: float,
     interpret: bool | None = None,
-) -> jax.Array:
+):
+    """(B, Hkv, group, D); with ``layer``, also the log-sum-exp (B, Hkv,
+    group) float32, NEG_INF where no token is live."""
+    if layer is not None:
+        return _paged_attention_layer(q, k_pool, v_pool, block_tables, seq_lens, layer,
+                                      scale=scale, interpret=interpret)
     B, Hkv, group, D = q.shape
     _, block_size, _, _ = k_pool.shape
     max_blocks = block_tables.shape[1]
@@ -113,3 +136,53 @@ def paged_attention(
         interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
     )(block_tables, seq_lens, q.transpose(0, 2, 1, 3), k_pool, v_pool)
     return out.transpose(0, 2, 1, 3)
+
+
+def _paged_attention_layer(
+    q: jax.Array,            # (B, Hkv, group, D)
+    k_pool: jax.Array,       # (L, num_blocks, block_size, Hkv, D)
+    v_pool: jax.Array,
+    block_tables: jax.Array,  # (B, max_blocks) int32, -1 padded
+    seq_lens: jax.Array,      # (B,) int32
+    layer: jax.Array,         # (1,) int32
+    *,
+    scale: float,
+    interpret: bool | None = None,
+):
+    B, Hkv, group, D = q.shape
+    block_size = k_pool.shape[2]
+    max_blocks = block_tables.shape[1]
+
+    def kv_index(b, j, tbl, lens, lay):
+        return (lay[0], jnp.maximum(tbl[b, j], 0), 0, 0, 0)
+
+    def q_index(b, j, tbl, lens, lay):
+        return (b, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, max_blocks),
+        in_specs=[
+            pl.BlockSpec((1, group, Hkv, D), q_index),
+            pl.BlockSpec((None, 1, block_size, Hkv, D), kv_index),
+            pl.BlockSpec((None, 1, block_size, Hkv, D), kv_index),
+        ],
+        out_specs=[pl.BlockSpec((1, group, Hkv, D), q_index),
+                   pl.BlockSpec((1, group, Hkv, 128), q_index)],
+        scratch_shapes=[
+            pltpu.VMEM((group, Hkv, 128), jnp.float32),
+            pltpu.VMEM((group, Hkv, 128), jnp.float32),
+            pltpu.VMEM((group, Hkv, D), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _paged_kernel_lse, scale=scale, block_size=block_size, n_blocks=max_blocks
+    )
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, group, Hkv, D), q.dtype),
+                   jax.ShapeDtypeStruct((B, group, Hkv, 128), jnp.float32)],
+        interpret=jax.default_backend() != "tpu" if interpret is None else interpret,
+    )(block_tables, seq_lens, layer, q.transpose(0, 2, 1, 3), k_pool, v_pool)
+    return out.transpose(0, 2, 1, 3), lse[..., 0].transpose(0, 2, 1)

@@ -35,3 +35,18 @@ def paged_attention_ref(
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)
     return jnp.einsum("bhgs,bhsd->bhgd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def paged_attention_layer_ref(q, k_pool, v_pool, block_tables, seq_lens, layer, *, scale):
+    """Layer ``layer[0]`` of every-layer pools: (out, log-sum-exp (B, Hkv,
+    group), -inf where no token is live)."""
+    B, Hkv, group, D = q.shape
+    k_pool, v_pool = k_pool[layer[0]], v_pool[layer[0]]
+    block_size = k_pool.shape[1]
+    S = block_tables.shape[1] * block_size
+    idx = jnp.maximum(block_tables, 0)
+    k = k_pool[idx].reshape(B, S, Hkv, D)
+    s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
+    s = jnp.where(jnp.arange(S)[None, None, None, :] < seq_lens[:, None, None, None], s, -jnp.inf)
+    return (paged_attention_ref(q, k_pool, v_pool, block_tables, seq_lens, scale=scale),
+            jax.nn.logsumexp(s, axis=-1))

@@ -83,15 +83,9 @@ def measure(arch: str, shape_name: str, variant: str) -> Dict[str, Any]:
     )
 
     if cfg.family == "hybrid":
-        every = cfg.attn_every
-        f6 = RL._pd(run_cell(arch, shape_name, n_layers=every, **base_kw))
-        f7 = RL._pd(run_cell(arch, shape_name, n_layers=every + 1, **base_kw))
-        f12 = RL._pd(run_cell(arch, shape_name, n_layers=2 * every, **base_kw))
-        Bm = {m: f7[m] - f6[m] for m in RL.METRICS}
-        Ba = {m: f12[m] - f6[m] - every * Bm[m] for m in RL.METRICS}
-        A = {m: f6[m] - every * Bm[m] - Ba[m] for m in RL.METRICS}
-        L = cfg.n_layers
-        tot = {m: A[m] + L * Bm[m] + (L // every) * Ba[m] for m in RL.METRICS}
+        fs = [RL._pd(run_cell(arch, shape_name, n_layers=n, **base_kw))
+              for n in RL.hybrid_depths(cfg)]
+        tot = RL.hybrid_terms(*fs, cfg)["total"]
     else:
         f1 = RL._pd(run_cell(arch, shape_name, n_layers=1, **base_kw))
         f2 = RL._pd(run_cell(arch, shape_name, n_layers=2, **base_kw))

@@ -13,10 +13,11 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 # XLA counts a lax.scan body ONCE, so scanned-layer models under-report.
 # We recover exact totals by compiling small UNROLLED variants and solving
 # the linear model  F(L) = A + L*B  (dense/moe/ssm/vlm/encdec), or
-# F = A + Lm*Bm + n_app*Ba for the hybrid (mamba layers + shared-attn
-# applications).  A is the fixed cost (embed, logits, loss, optimizer),
-# B the per-layer cost; every reported quantity (flops, bytes, collective
-# bytes) is extrapolated with the same coefficients.  Peak memory comes from
+# F = A + Lm*Bm + n_app*Ba for the hybrid (mamba layers + shared-block
+# uses, from depths with no use, one use, and one use and one more layer).
+# A is the fixed cost (embed, logits, loss, optimizer), B the per-layer
+# cost; every reported quantity (flops, bytes, collective bytes) is
+# extrapolated with the same coefficients.  Peak memory comes from
 # the full-size scanned dry-run compile (no extrapolation).
 import argparse
 import json
@@ -47,6 +48,26 @@ def _lin2(f1: Dict, f2: Dict) -> Dict[str, Dict[str, float]]:
     return {"A": A, "B": B}
 
 
+def hybrid_depths(cfg) -> tuple:
+    """Depths whose unrolled costs separate a hybrid's terms: the Mamba
+    layers before the first shared-block use (no use), one more (the first
+    use and its layer) and two more (one more plain layer)."""
+    first = cfg.hybrid_layer_ids[0]
+    assert first + 1 not in cfg.hybrid_layer_ids, cfg.hybrid_layer_ids
+    return first, first + 1, first + 2
+
+
+def hybrid_terms(f0: Dict, f1: Dict, f2: Dict, cfg) -> Dict[str, Any]:
+    """F = A + L*Bm + n_app*Ba from the costs at ``hybrid_depths``."""
+    first = cfg.hybrid_layer_ids[0]
+    Bm = {m: f2[m] - f1[m] for m in METRICS}
+    Ba = {m: f1[m] - f0[m] - Bm[m] for m in METRICS}
+    A = {m: f0[m] - first * Bm[m] for m in METRICS}
+    n_app = len(cfg.hybrid_uses)
+    total = {m: A[m] + cfg.n_layers * Bm[m] + n_app * Ba[m] for m in METRICS}
+    return {"total": total, "fixed": A, "per_layer": Bm, "per_attn_app": Ba}
+
+
 def extrapolate(arch: str, shape_name: str, *, attn_impl: str) -> Dict[str, Any]:
     """Per-device totals for the full layer count, via unrolled variants.
 
@@ -58,23 +79,10 @@ def extrapolate(arch: str, shape_name: str, *, attn_impl: str) -> Dict[str, Any]
               accum_steps=1)
 
     if cfg.family == "hybrid":
-        every = cfg.attn_every
-        f6 = _pd(run_cell(arch, shape_name, n_layers=every, **kw))
-        f7 = _pd(run_cell(arch, shape_name, n_layers=every + 1, **kw))
-        f12 = _pd(run_cell(arch, shape_name, n_layers=2 * every, **kw))
-        Bm = {m: f7[m] - f6[m] for m in METRICS}
-        Ba = {m: f12[m] - f6[m] - every * Bm[m] for m in METRICS}
-        A = {m: f6[m] - every * Bm[m] - Ba[m] for m in METRICS}
-        L = cfg.n_layers
-        n_app = L // every
-        total = {m: A[m] + L * Bm[m] + n_app * Ba[m] for m in METRICS}
-        return {
-            "total": total,
-            "fixed": A,
-            "per_layer": Bm,
-            "per_attn_app": Ba,
-            "samples": {"L6": f6, "L7": f7, "L12": f12},
-        }
+        depths = hybrid_depths(cfg)
+        fs = [_pd(run_cell(arch, shape_name, n_layers=n, **kw)) for n in depths]
+        return {**hybrid_terms(*fs, cfg),
+                "samples": {f"L{n}": f for n, f in zip(depths, fs)}}
 
     f1 = _pd(run_cell(arch, shape_name, n_layers=1, **kw))
     f2 = _pd(run_cell(arch, shape_name, n_layers=2, **kw))

@@ -16,7 +16,8 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.configs.registry import get_config, lm_archs
-from repro.core.kv_pool import KVPoolConfig
+from repro.core.kv_pool import KVPoolConfig, lane_dim
+from repro.models import mamba2 as M2
 from repro.models.transformer import LM
 from repro.serve.engine import Request, ServeEngine
 
@@ -41,12 +42,17 @@ def pool_config(
     cfg: ModelConfig, *, max_seqs: int, policy: str = "puma"
 ) -> KVPoolConfig:
     """The serving pool for ``cfg``: 512 pages of 16 tokens (a page fills
-    bf16 sublanes) in ``cfg.kv_cache_dtype``, up to 512 tokens a sequence."""
+    bf16 sublanes) in ``cfg.kv_cache_dtype``, up to 512 tokens a sequence;
+    a hybrid's pages hold its shared-block uses and its Mamba2 layers get a
+    state slot per sequence."""
+    hybrid = cfg.family == "hybrid"
+    states = {"state_layers": cfg.n_layers, **M2.slot_widths(cfg)} if hybrid else {}
     return KVPoolConfig(
         num_blocks=512, block_size=16, kv_heads=cfg.n_kv_heads,
-        head_dim=cfg.hd, n_layers=cfg.n_layers, max_seqs=max_seqs,
+        head_dim=lane_dim(cfg.hd) if hybrid else cfg.hd, n_layers=cfg.kv_layers,
+        max_seqs=max_seqs,
         max_blocks_per_seq=32, blocks_per_arena=64, policy=policy,
-        dtype=cfg.kv_cache_dtype,
+        dtype=cfg.kv_cache_dtype, **states,
     )
 
 
@@ -66,10 +72,10 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
-    if cfg.family in ("ssm", "hybrid", "encdec"):
+    if cfg.family in ("ssm", "encdec"):
         raise SystemExit(
-            f"{args.arch}: paged-KV serving applies to attention-KV archs; "
-            "SSM/hybrid state serving uses the dense decode path"
+            f"{args.arch}: paged serving applies to attention-KV and Mamba2-hybrid "
+            "archs; RWKV and encoder-decoder serving use the dense decode path"
         )
     model = LM(cfg, attn_impl="naive", remat=None)
     params = model.init(jax.random.key(0))

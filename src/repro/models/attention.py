@@ -216,10 +216,11 @@ def apply_attention(
     cache: Optional[Cache] = None,
     cache_len: Optional[jax.Array] = None,   # scalar int32: tokens already cached
     kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,  # cross-attn
+    scale: Optional[float] = None,           # default 1/sqrt(head_dim)
 ) -> Tuple[jax.Array, Optional[Cache]]:
     B, S, d = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
     if cache is not None and S == 1:

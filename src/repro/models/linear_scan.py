@@ -1,6 +1,7 @@
-"""Chunked linear attention with per-step decay — shared by RWKV6 (Finch,
-per-channel data-dependent decay + bonus) and Mamba2 (SSD, per-head scalar
-decay).
+"""Chunked linear attention with per-step decay — RWKV6's (Finch,
+per-channel data-dependent decay + bonus); the ``bonus=None`` form is the
+decay kernel's oracle.  Mamba2 has its own SSD chunks (``models/mamba2.py``),
+which need no clamp of the decay.
 
 Recurrence (state S: (dk, dv) per head):
 

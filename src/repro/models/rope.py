@@ -1,4 +1,5 @@
-"""Rotary position embeddings: standard, partial (ChatGLM-style 2D), and
+"""Rotary position embeddings: standard (interleaved pairs), rotate-half
+(GPT-NeoX / Zamba2), partial (ChatGLM-style 2D), and
 M-RoPE (Qwen2-VL: separate temporal/height/width sections)."""
 from __future__ import annotations
 
@@ -38,6 +39,15 @@ def apply_rope(
     if cfg.rope == "rope":
         cos, sin = _angles(positions, hd, cfg.rope_theta)      # (B,S,hd/2)
         return _rot_half_pairs(x, cos[:, :, None, :], sin[:, :, None, :])
+
+    if cfg.rope == "rope_half":
+        # rotate-half (GPT-NeoX / Zamba2): channel i pairs with i + hd/2
+        cos, sin = _angles(positions, hd, cfg.rope_theta)
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., : hd // 2], xf[..., hd // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.astype(x.dtype)
 
     if cfg.rope == "rope2d":
         # ChatGLM: rotary over the first half of channels only.

@@ -17,8 +17,8 @@ Families:
   dense   — [norm-attn-res, norm-mlp-res] x L (GQA/MQA, RoPE variants)
   moe     — dense attention + top-k routed experts (aux loss carried)
   ssm     — RWKV6 time-mix + channel-mix
-  hybrid  — Mamba2 backbone with one *shared-weight* attention block applied
-            every ``attn_every`` layers (zamba2)
+  hybrid  — Mamba2 backbone with shared-weight attention blocks used in
+            turn before the layers ``hybrid_layer_ids`` names (zamba2)
   encdec  — bidirectional encoder + causal decoder with cross-attention
   vlm     — dense + M-RoPE, patch embeddings spliced into the token stream
 """
@@ -39,6 +39,8 @@ from repro.models import mamba2 as M2
 from repro.models import rwkv6 as R6
 from repro.models.attention import apply_attention, attn_defs
 from repro.models.params import ParamDef, init_params, spec_tree
+
+__all__ = ["LM", "shared_block_input", "shared_block_mlp"]
 
 # ---------------------------------------------------------------------------
 # helpers
@@ -140,10 +142,64 @@ def _rwkv_block(lp, cfg, impl, x, pos, state, cache_len):
     return x, new_state, jnp.zeros((), jnp.float32)
 
 
-def _mamba_block(lp, cfg, impl, x, state):
-    h = L.apply_norm(lp["ln"], x)
+# ---------------------------------------------------------------------------
+# hybrid (zamba2): Mamba2 layers, shared attention blocks used between them
+# ---------------------------------------------------------------------------
+
+def _mamba_block(lp, cfg, x, state, T=None):
+    """One Mamba2 decoder layer.  ``T``, a shared-block use's output, joins
+    the mixer's input only: the residual is taken before it is added."""
+    h = L.apply_norm(lp["ln"], x if T is None else x + T, cfg.norm_eps)
     a, new_state = M2.apply_mamba(lp["mamba"], cfg, h, state)
     return x + a, new_state
+
+
+def shared_block_defs(cfg: ModelConfig) -> Dict:
+    """One shared block: attention over the 2·d concat (no residual),
+    then the pre-MLP norm and a gelu MLP with a fused gate_up."""
+    d, H, KV, hd, f = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_ff
+    return {
+        "ln1": L.norm_defs(cfg, 2 * d),
+        "wq": ParamDef((2 * d, H, hd), ("embed", "heads", "head_dim")),
+        "wk": ParamDef((2 * d, KV, hd), ("embed", "kv_heads", "head_dim")),
+        "wv": ParamDef((2 * d, KV, hd), ("embed", "kv_heads", "head_dim")),
+        "wo": ParamDef((H, hd, d), ("heads", "head_dim", "embed")),
+        "ln2": L.norm_defs(cfg),
+        "w_gate_up": ParamDef((d, 2 * f), ("embed", "mlp")),
+        "w_down": ParamDef((f, d), ("mlp", "embed")),
+    }
+
+
+def use_defs(cfg: ModelConfig) -> Dict:
+    """What one use of a shared block has of its own: the LoRA on gate_up
+    and the linear into the next Mamba layer's input."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    return {
+        "lora_a": ParamDef((d, r), ("embed", None)),
+        "lora_b": ParamDef((r, 2 * f), (None, "mlp")),
+        "linear": ParamDef((d, d), ("embed", None)),
+    }
+
+
+def shared_block_input(sp: Dict, cfg: ModelConfig, x: jax.Array, e: jax.Array) -> jax.Array:
+    """A shared block's normed input: the residual stream ``x`` beside the
+    embedding output ``e``, 2·d wide."""
+    return L.apply_norm(sp["ln1"], jnp.concatenate([x, e], axis=-1), cfg.norm_eps)
+
+
+def shared_block_mlp(sp: Dict, up: Dict, cfg: ModelConfig, a: jax.Array) -> jax.Array:
+    """A use after its attention output ``a`` (B, S, d): the pre-MLP norm,
+    gate_up plus the use's LoRA, gelu(gate)·up, down, and the use's
+    linear.  Returns T, which joins the next Mamba layer's input."""
+    dt = a.dtype
+    h = L.apply_norm(sp["ln2"], a, cfg.norm_eps)
+    lora = jnp.einsum("bsd,dr->bsr", h, up["lora_a"].astype(dt))
+    gu = (jnp.einsum("bsd,df->bsf", h, sp["w_gate_up"].astype(dt))
+          + jnp.einsum("bsr,rf->bsf", lora, up["lora_b"].astype(dt)))
+    g, u = jnp.split(gu, 2, axis=-1)
+    m = jnp.einsum("bsf,fd->bsd", jax.nn.gelu(g, approximate=False) * u,
+                   sp["w_down"].astype(dt))
+    return jnp.einsum("bsd,de->bse", m, up["linear"].astype(dt))
 
 
 # ---------------------------------------------------------------------------
@@ -215,12 +271,8 @@ class LM:
             return defs
         defs["layers"] = stack_defs(self._layer_defs(), cfg.n_layers)
         if cfg.family == "hybrid":
-            defs["shared_attn"] = {
-                "ln": L.norm_defs(cfg),
-                "attn": attn_defs(cfg),
-                "ln2": L.norm_defs(cfg),
-                "mlp": L.mlp_defs(cfg),
-            }
+            defs["shared"] = stack_defs(shared_block_defs(cfg), cfg.num_mem_blocks)
+            defs["uses"] = stack_defs(use_defs(cfg), len(cfg.hybrid_uses))
         return defs
 
     def init(self, key: jax.Array) -> Dict:
@@ -323,88 +375,56 @@ class LM:
         return k, v
 
     def _run_hybrid(self, params, x, pos, caches, cache_len):
-        """Zamba2: mamba stack in groups of ``attn_every`` with the shared
-        attention block between groups.  Stacked mamba params are reshaped to
-        (groups, attn_every, ...) and scanned; the remainder runs after."""
+        """Zamba2: the Mamba2 layers in order; before each layer of
+        ``hybrid_uses`` one use of a shared block reads the residual stream
+        beside the embedding output ``x`` enters with, and its output joins
+        that layer's input.  The layers are unrolled, each taking its
+        weights from the stacks at an index the compiler cannot fold: a
+        scan's, or a folded index's, weight casts become one cast of the
+        whole stack, which a chip-sized prefill has no memory for."""
         cfg = self.cfg
-        impl = self.attn_impl
-        every = cfg.attn_every
-        n_groups, rem = divmod(cfg.n_layers, every)
-        sa = params["shared_attn"]
+        e = x
+        uses = cfg.hybrid_uses
+        zero = jnp.minimum(jnp.min(pos), 0)
+        at = lambda tree, i: jax.tree.map(  # noqa: E731
+            lambda a: jax.lax.dynamic_index_in_dim(a, i + zero, keepdims=False), tree)
+        mstates = caches["mamba"] if caches is not None else None
+        acaches = caches["attn"] if caches is not None else None
 
-        mamba_states = caches["mamba"] if caches is not None else None
-        attn_caches = caches["attn"] if caches is not None else None
+        def layer(xc, i, T):
+            st = None if mstates is None else jax.tree.map(lambda a: a[i], mstates)
+            return _mamba_block(at(params["layers"], i), cfg, xc, st, T)
 
-        def mamba_body(carry, xs):
-            xc = carry
-            lp, st = xs
-            xc, new_st = _mamba_block(lp, cfg, impl, xc, st)
-            return xc, new_st
+        layer = _remat(layer, self.remat)
+        new_states, new_recent = [], []
+        for i in range(cfg.n_layers):
+            T = None
+            if i in uses:
+                u = uses.index(i)
+                cache = None if acaches is None else jax.tree.map(lambda a: a[u], acaches)
+                sp = at(params["shared"], u % cfg.num_mem_blocks)
+                T, new_cache = self._shared_use(sp, at(params["uses"], u), x, e, pos, cache,
+                                                cache_len)
+                new_recent.append(new_cache)
+            x, st = layer(x, i, T)
+            new_states.append(st)
+        if caches is None:
+            return x, None, jnp.zeros((), jnp.float32)
+        recent = (jax.tree.map(lambda *a: jnp.stack(a), *[c["recent"] for c in new_recent])
+                  if new_recent else acaches["recent"])
+        merged = jax.tree.map(lambda *a: jnp.stack(a), *new_states)
+        return x, {"mamba": merged, "attn": {"recent": recent}}, jnp.zeros((), jnp.float32)
 
-        mamba_body = _remat(mamba_body, self.remat)
-
-        def take(tree, lo, hi):
-            return jax.tree.map(lambda a: a[lo:hi], tree)
-
-        def group_reshape(tree, g, e):
-            return jax.tree.map(
-                lambda a: a[: g * e].reshape((g, e) + a.shape[1:]), tree
-            )
-
-        main_params = group_reshape(params["layers"], n_groups, every)
-        main_states = (
-            group_reshape(mamba_states, n_groups, every)
-            if mamba_states is not None
-            else None
+    def _shared_use(self, sp, up, x, e, pos, cache, cache_len):
+        """One use of a shared block (``sp``) with its own weights ``up``:
+        its T and its KV cache (attention scale (hd/2)^-0.5, as Zamba2 has
+        it)."""
+        cfg = self.cfg
+        a, new_cache = apply_attention(
+            sp, cfg, shared_block_input(sp, cfg, x, e), pos, impl=self.attn_impl,
+            causal=True, cache=cache, cache_len=cache_len, scale=(cfg.hd / 2) ** -0.5,
         )
-
-        def shared_block(xc, cache, clen):
-            h = L.apply_norm(sa["ln"], xc)
-            a, new_cache = apply_attention(
-                sa["attn"], cfg, h, pos,
-                impl=impl, causal=True, cache=cache, cache_len=clen,
-            )
-            xc = xc + a
-            h = L.apply_norm(sa["ln2"], xc)
-            return xc + L.apply_mlp(sa["mlp"], h), new_cache
-
-        def group_body(carry, xs):
-            xc = carry
-            gp, gst, acache = xs
-            xc, new_gst = scan_or_loop(mamba_body, xc, (gp, gst), self.scan_layers)
-            xc, new_acache = shared_block(xc, acache, cache_len)
-            return xc, (new_gst, new_acache)
-
-        x, (new_main_states, new_attn_caches) = scan_or_loop(
-            group_body, x, (main_params, main_states, attn_caches),
-            self.scan_layers,
-        )
-
-        new_states = None
-        if rem:
-            rem_params = take(params["layers"], n_groups * every, cfg.n_layers)
-            rem_states = (
-                take(mamba_states, n_groups * every, cfg.n_layers)
-                if mamba_states is not None
-                else None
-            )
-            x, new_rem_states = scan_or_loop(
-                mamba_body, x, (rem_params, rem_states), self.scan_layers
-            )
-        if mamba_states is not None:
-            flat_main = jax.tree.map(
-                lambda a: a.reshape((n_groups * every,) + a.shape[2:]),
-                new_main_states,
-            )
-            if rem:
-                merged = jax.tree.map(
-                    lambda a, b: jnp.concatenate([a, b], axis=0),
-                    flat_main, new_rem_states,
-                )
-            else:
-                merged = flat_main
-            new_states = {"mamba": merged, "attn": new_attn_caches}
-        return x, new_states, jnp.zeros((), jnp.float32)
+        return shared_block_mlp(sp, up, cfg, a), new_cache
 
     def _run_encoder(self, params, enc_embeds):
         cfg = self.cfg
@@ -437,7 +457,7 @@ class LM:
             enc_out=enc_out,
             enc_len=enc_out.shape[1] if enc_out is not None else None,
         )
-        x = L.apply_norm(params["final_ln"], x)
+        x = L.apply_norm(params["final_ln"], x, cfg.norm_eps)
         logits = L.logits_from(params["embed"], x)
         logits = constraint(logits, "batch", None, "vocab")
         loss = cross_entropy(logits, batch["targets"], batch.get("loss_mask"))
@@ -456,7 +476,7 @@ class LM:
             enc_out=enc_out,
             enc_len=enc_out.shape[1] if enc_out is not None else None,
         )
-        x = L.apply_norm(params["final_ln"], x[:, -1:])
+        x = L.apply_norm(params["final_ln"], x[:, -1:], cfg.norm_eps)
         logits = L.logits_from(params["embed"], x)[:, 0]
         return constraint(logits, "batch", "vocab")
 
@@ -474,7 +494,7 @@ class LM:
             params, x, pos, cache["layers"], cache_len,
             enc_len=cache.get("enc_len"),
         )
-        x = L.apply_norm(params["final_ln"], x[:, -1:])
+        x = L.apply_norm(params["final_ln"], x[:, -1:], cfg.norm_eps)
         logits = L.logits_from(params["embed"], x)[:, 0]
         new_cache = dict(cache)
         new_cache["layers"] = self._merge_layer_caches(
@@ -584,15 +604,11 @@ class LM:
             )
             return {"layers": stacked, "len": jnp.zeros((), jnp.int32)}
         if cfg.family == "hybrid":
-            st = M2.init_mamba_state(cfg, batch_size, self.dtype)
-            n_apps = cfg.n_layers // cfg.attn_every
+            st = M2.init_mamba_state(cfg, batch_size)
             return {
                 "layers": {
-                    "mamba": M2.MambaState(
-                        conv=jnp.zeros((Lr,) + st.conv.shape, st.conv.dtype),
-                        ssd=jnp.zeros((Lr,) + st.ssd.shape, st.ssd.dtype),
-                    ),
-                    "attn": split_kv(n_apps, max_len),
+                    "mamba": jax.tree.map(lambda a: jnp.zeros((Lr,) + a.shape, a.dtype), st),
+                    "attn": split_kv(cfg.kv_layers, max_len),
                 },
                 "len": jnp.zeros((), jnp.int32),
                 "len_rec": jnp.zeros((), jnp.int32),

@@ -8,11 +8,13 @@ Lifecycle per step:
      large head-of-line request cannot starve small requests behind it.
   2. **prefill** — teacher-forced pass with a dense scratch cache, then every
      layer's K/V pages are written into the pool blocks in one in-place call
-     (a bulk RowClone-style block write).
+     (a bulk RowClone-style block write); a hybrid also writes each Mamba2
+     layer's final state into the sequence's state slot in one call.
   3. **decode** — one fused step for every live sequence via
      ``paged_decode_step`` (block tables + seq_lens), then the batch's
      new-token K/V, every layer, written to the PUMA-chosen blocks in one
-     in-place call; greedy sampling.
+     in-place call (a hybrid's new states to their slots in one more);
+     greedy sampling.
   4. **bookkeeping** — tokens appended (``extend`` keeps arena locality),
      finished sequences release blocks.
 
@@ -58,9 +60,10 @@ their only switch:
   * ``serve.step`` — the whole ``step()``, hooks included; sequence
     bookkeeping (token append, release) is its self time;
   * ``serve.admit`` — the deadline sweep and the admission scan;
-  * ``serve.prefill`` (``rid``, ``tokens``) — one request's jitted prefill,
-    its one ``write_prompt_kv`` call and first-token argmax (inside
-    ``admit``);
+  * ``serve.prefill`` (``rid``, ``tokens``, ``states``) — one request's
+    jitted prefill, its one ``write_prompt_kv`` call (and, ``states`` > 0,
+    its one ``write_prompt_states`` of that many layers) and first-token
+    argmax (inside ``admit``);
   * ``serve.decode_dispatch`` (``batch``) — block tables, ``seq_lens``,
     host-to-device inputs and the asynchronous call of the paged step;
   * ``serve.kv_writeback`` (``rid``) — one sequence's share of the write:
@@ -83,7 +86,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
-from repro.core.kv_pool import KVPoolConfig, PagedKVPool
+from repro.core.kv_pool import KVPoolConfig, PagedKVPool, lane_dim
 from repro.robustness import (
     ClientCancelled,
     DeadlineExceeded,
@@ -160,8 +163,17 @@ class ServeEngine:
         trace=None,
     ):
         cfg = model.cfg
-        assert pool_cfg.kv_heads == cfg.n_kv_heads and pool_cfg.head_dim == cfg.hd
-        assert pool_cfg.n_layers == cfg.n_layers
+        if cfg.family in ("ssm", "encdec") or cfg.is_encdec:
+            raise ValueError(f"{cfg.name}: the paged engine serves attention and "
+                             f"Mamba2-hybrid families, not {cfg.family}")
+        hybrid = cfg.family == "hybrid"
+        want = (cfg.n_kv_heads, lane_dim(cfg.hd) if hybrid else cfg.hd, cfg.kv_layers,
+                cfg.n_layers if hybrid else 0)
+        have = (pool_cfg.kv_heads, pool_cfg.head_dim, pool_cfg.n_layers,
+                pool_cfg.state_layers)
+        if have != want:
+            raise ValueError(f"pool (kv_heads, head_dim, KV layers, state layers) "
+                             f"{have} does not fit {cfg.name}: {want}")
         self.model = model
         self.cfg = cfg
         self.params = params
@@ -380,7 +392,8 @@ class ServeEngine:
         (recompute-on-resume).  Returns False if the request had to be
         rejected (pathological: pool cannot host the sampled token)."""
         ctx = req.prompt + req.out[:-1]
-        with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(ctx)):
+        states = self.pool.cfg.state_layers
+        with TraceAnnotation("serve.prefill", rid=req.rid, tokens=len(ctx), states=states):
             toks = jnp.asarray([ctx], jnp.int32)
             S = toks.shape[1]
             pos = jnp.arange(S, dtype=jnp.int32)[None]
@@ -389,7 +402,12 @@ class ServeEngine:
             logits, cache = self._decode_step(self.params, batch, cache)
             self.tokens_prefilled += S
             # prompt KV lands in the recent ring (split cache, len_main == 0)
-            k, v = cache["layers"]["recent"]            # (L, 1, S, KV, hd)
+            layers = cache["layers"]
+            if states:
+                st = layers["mamba"]
+                self.pool.write_prompt_states(req.slot, st.conv, st.ssd)
+                layers = layers["attn"]
+            k, v = layers["recent"]                     # (L, 1, S, KV, hd)
             self.pool.write_prompt_kv(req.slot, k, v)
             if self.trace is not None:
                 self.trace.on_prefill(
@@ -507,11 +525,14 @@ class ServeEngine:
             positions = np.array([[lens_full[s] - 1] for s in slots], np.int32)
             tbl = jnp.asarray(tbl_full[slots])
             lens = jnp.asarray(lens_full[slots])
+            # a hybrid's state slots are read by slot and written back below
+            state = (() if self.pool.conv is None else
+                     ((self.pool.conv, self.pool.ssd, jnp.asarray(slots, jnp.int32)),))
 
-            logits, new_k, new_v = self._paged_step(
+            logits, new_k, new_v, *new_states = self._paged_step(
                 self.params, cfg,
                 jnp.asarray(tokens), jnp.asarray(positions),
-                self.pool.k, self.pool.v, tbl, lens,
+                self.pool.k, self.pool.v, tbl, lens, *state,
                 use_kernel=self.use_kernel,
             )
         # 3) write every sequence's current-token KV into its PUMA-placed
@@ -523,6 +544,8 @@ class ServeEngine:
             with TraceAnnotation("serve.kv_writeback", rid=self.live[slot].rid):
                 dests.append(self.pool.token_dest(slot))
         self.pool.write_token_kv(dests, new_k, new_v)
+        if new_states:
+            self.pool.write_token_states(slots, *new_states)
         with TraceAnnotation("serve.sample"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
 

@@ -7,6 +7,8 @@ one process may load the TPU library, and test workers all import this file.
 """
 import dataclasses
 import functools
+import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -49,8 +51,9 @@ def _compile(fn, *args):
     return compiled
 
 
-# (Hq, Hkv, D): stablelm-1.6b, chatglm3-6b, granite-moe-1b, granite-34b
-@pytest.mark.parametrize("hq,hkv,d", [(32, 32, 64), (32, 2, 128), (16, 8, 64), (48, 1, 128)])
+# (Hq, Hkv, D): stablelm-1.6b, chatglm3-6b, granite-moe-1b, granite-34b, zamba2-7b
+@pytest.mark.parametrize("hq,hkv,d", [(32, 32, 64), (32, 2, 128), (16, 8, 64), (48, 1, 128),
+                                      (32, 32, 224)])
 def test_paged_attention_compiles(spec, hq, hkv, d):
     B, nb, bs, maxb = 8, 512, 16, 32
     pool = spec((nb, bs, hkv, d), jnp.bfloat16)
@@ -126,3 +129,71 @@ def test_pool_writes_compile_in_place(spec, write):
     assert not [l for l in copies if "bf16[24,1088,16,32,64]" in l]
     if write == "token":
         assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+def _zamba2_cell():
+    """The zamba2 cell's program config and pool, from the benchmark's files."""
+    import sys
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from chipbench import reference, traffic
+    from chipbench.families import zamba2
+
+    cfg_file = json.loads((root / "chipbench/configs/zamba2_7b_18layers.json").read_text())
+    pcfg = zamba2.program_config(cfg_file, reference.spec_of(cfg_file))
+    mix = traffic.load_mix(root / "chipbench/traffic/longprompt_16s.json")
+    return pcfg, zamba2.pool_config(pcfg, mix)
+
+
+def test_hybrid_paged_step_compiles_at_the_cell_shapes(spec, monkeypatch, capsys):
+    # zamba2-7b cut to 18 layers, batch 16, the cell's 2176-page pool and
+    # state slots: the step fits one chip with the whole model and pool
+    # resident, and reads the pool in place (a row-major layout, no copy)
+    pcfg, pc = _zamba2_cell()
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(LM(pcfg, attn_impl="naive", remat=None).init, jax.random.key(0)),
+    )
+    pool = spec((pc.n_layers, pc.num_blocks, pc.block_size, pc.kv_heads, pc.head_dim),
+                jnp.dtype(pc.dtype))
+    conv = spec((pc.max_seqs, pc.state_layers, pc.conv_width), jnp.float32)
+    ssd = spec((pc.max_seqs, pc.state_layers, pc.ssd_heads, pc.ssd_width), jnp.float32)
+    B = pc.max_seqs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        compiled = _compile(
+            lambda p, *xs: paged_decode_step(p, pcfg, *xs[:6], xs[6:], use_kernel=True),
+            params, spec((B, 1), jnp.int32), spec((B, 1), jnp.int32), pool, pool,
+            spec((B, pc.max_blocks_per_seq), jnp.int32), spec((B,), jnp.int32), conv, ssd,
+            spec((B,), jnp.int32),
+        )
+    finally:
+        jax.clear_caches()
+    formats = compiled.input_formats[0]      # (params, tokens, positions, k, v, ...)
+    layouts = {name: formats[i].layout.major_to_minor for name, i in
+               (("k_pool", 3), ("v_pool", 4), ("conv", 7), ("ssd", 8))}
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nzamba2 paged step: layouts {layouts}; arguments "
+              f"{mem.argument_size_in_bytes}, temporaries {mem.temp_size_in_bytes}, "
+              f"outputs {mem.output_size_in_bytes} B")
+    # the pages and the SSD state row-major: the kernel and the slot writes
+    # read them in place (the 25 MB conv state may go layer-major)
+    assert all(layouts[n] == tuple(range(len(layouts[n]))) for n in ("k_pool", "v_pool", "ssd"))
+    pool_type = f"bf16[{','.join(map(str, pool.shape))}]"
+    assert not [l for l in compiled.as_text().splitlines() if " copy(" in l and pool_type in l]
+
+
+def test_state_write_compiles_in_place(spec):
+    _, pc = _zamba2_cell()
+    L, S, B = pc.state_layers, pc.max_seqs, pc.max_seqs
+    conv = spec((S, L, pc.conv_width), jnp.float32)
+    ssd = spec((S, L, pc.ssd_heads, pc.ssd_width), jnp.float32)
+    compiled = kv_pool._write_states.lower(
+        conv, ssd, spec((B,), jnp.int32), spec((L, B, pc.conv_width), jnp.float32),
+        spec((L, B, pc.ssd_heads, pc.ssd_width), jnp.float32)).compile()
+    state_bytes = 4 * S * L * (pc.conv_width + pc.ssd_heads * pc.ssd_width)
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes   # both donated
+    ssd_type = f"f32[{S},{L},{pc.ssd_heads},{pc.ssd_width}]"
+    assert not [l for l in compiled.as_text().splitlines() if " copy(" in l and ssd_type in l]
