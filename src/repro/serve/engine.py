@@ -72,11 +72,30 @@ their only switch:
     self time of ``serve.step``;
   * ``serve.sample`` — the host waiting for the step's argmax (the device
     runs the step, then the write, then the argmax);
-  * ``serve.maintain`` — a compaction pass, only when one runs.
+  * ``serve.maintain`` — a compaction pass, only when one runs;
+  * ``serve.cast_weights`` (``bytes``) — once, in the constructor: the one
+    jitted cast of the weights to the compute dtype (below).
+
+Weights in the compute dtype: the paged step and the prefill cast every
+weight matrix to ``cfg.dtype`` before they use it, so f32 parameters would
+be read at 4 bytes a weight, cast and read again at 2 in every call.  Once
+the pool exists, the engine casts the matrices (``COMPUTE_DTYPE_LEAVES``)
+to ``cfg.dtype`` in one call and serves from that copy; the step's own
+casts are then no-ops and the matmuls see the same values.  Leaves read in
+f32 (norms, biases, Mamba2's ``A_log``, ``D``, ``dt_bias`` and conv) stay
+as they are, and the caller's arrays are neither changed nor freed.  The
+copy is made only where it fits: its bytes plus ``CAST_RESERVE_BYTES`` for
+the step's temporaries within the free bytes (``bytes_limit -
+bytes_in_use``) of every device the weights are on; a device that reports
+no memory stats (the CPU) has room.  Otherwise every matrix stays as given.
+``metrics()["weights_in_compute_dtype"]`` is the share of the matrices'
+elements held in ``cfg.dtype``: 1.0 after the cast, or where the weights
+came in it.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from typing import TYPE_CHECKING, Deque, Dict, List, Optional
 
@@ -97,6 +116,39 @@ from repro.serve.paged_runner import paged_decode_step, paged_decode_step_jit
 
 if TYPE_CHECKING:
     from repro.robustness.faults import FaultInjector
+
+#: parameter leaves that every use in the paged step and the prefill casts to
+#: the compute dtype first: attention projections, MLP and MoE matrices, the
+#: embedding and untied head, Mamba2's in/out projections, a shared block's
+#: matrices and each use's adapter and linear
+COMPUTE_DTYPE_LEAVES = frozenset({
+    "wq", "wk", "wv", "wo", "wg", "wu", "router", "tok", "head", "in_proj", "out_proj",
+    "w_gate_up", "w_down", "lora_a", "lora_b", "linear",
+})
+
+#: HBM left free beside the weights' copy for the step's and the prefill's
+#: temporaries: the widest any benchmark cell's program needs, zamba2's hybrid
+#: step at 2.57 GB (its compile for a v5e in ``tests/test_tpu_compile.py``)
+CAST_RESERVE_BYTES = 2_570_000_000
+
+
+def cast_fits(needed: int, stats: Optional[Dict[str, int]]) -> bool:
+    """Whether a copy of ``needed`` bytes fits, with ``CAST_RESERVE_BYTES``
+    to spare, a device whose ``memory_stats()`` are ``stats``; a device that
+    reports none has room."""
+    if not stats or "bytes_limit" not in stats:
+        return True
+    return needed + CAST_RESERVE_BYTES <= stats["bytes_limit"] - stats["bytes_in_use"]
+
+
+def _memory_stats(device) -> Optional[Dict[str, int]]:
+    """The device's memory stats (a test plants a full device here)."""
+    return device.memory_stats()
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves, dtype):
+    return [a.astype(dtype) for a in leaves]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,8 +228,8 @@ class ServeEngine:
                              f"{have} does not fit {cfg.name}: {want}")
         self.model = model
         self.cfg = cfg
-        self.params = params
         self.pool = PagedKVPool(pool_cfg, injector=injector)
+        self.params, self.weights_in_compute_dtype = self._in_compute_dtype(params)
         #: the Pallas kernels on the chip; elsewhere the jnp reference (the
         #: kernels would only run in the slow Pallas interpreter there)
         self.use_kernel = jax.default_backend() == "tpu"
@@ -229,6 +281,28 @@ class ServeEngine:
         self.trace = trace
         self.pool.trace = trace
         self._step_writes: List = []   # (slot, block) token writes this step
+
+    def _in_compute_dtype(self, params):
+        """``params`` with the matrices in ``cfg.dtype`` where the copy fits
+        (module docstring), and the share of their elements held in it."""
+        dtype = jnp.dtype(self.cfg.dtype)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        leaves = [a for _, a in flat]
+        mats = [i for i, (path, _) in enumerate(flat)
+                if getattr(path[-1], "key", None) in COMPUTE_DTYPE_LEAVES]
+        todo = [i for i in mats if leaves[i].dtype != dtype]
+        needed = sum(leaves[i].size for i in todo) * dtype.itemsize
+        devices = {d for i in todo for d in leaves[i].devices()}
+        # the pool's buffers count in ``bytes_in_use`` once they exist
+        jax.block_until_ready((self.pool.k, self.pool.v, self.pool.conv, self.pool.ssd))
+        if todo and all(cast_fits(needed, _memory_stats(d)) for d in devices):
+            with TraceAnnotation("serve.cast_weights", bytes=needed):
+                for i, a in zip(todo, _cast([leaves[i] for i in todo], dtype)):
+                    leaves[i] = a
+            params = jax.tree.unflatten(treedef, leaves)
+        total = sum(leaves[i].size for i in mats)
+        held = sum(leaves[i].size for i in mats if leaves[i].dtype == dtype)
+        return params, held / total if total else 1.0
 
     # -- submission -----------------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -687,6 +761,7 @@ class ServeEngine:
             maintenance_ns=float(self.maintenance_ns),
             compaction_passes=float(self.compaction_passes),
             blocks_migrated=float(self.blocks_migrated),
+            weights_in_compute_dtype=float(self.weights_in_compute_dtype),
         )
         return rep
 

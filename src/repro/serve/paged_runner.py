@@ -10,6 +10,11 @@ the dense cache swapped for (k_pool, v_pool, block_table, seq_lens); layer
 loop is unrolled (serving configs are small; the dry-run path uses the
 scanned dense-cache step).
 
+Every weight matrix is cast to ``cfg.dtype`` where it is used, so the step
+takes parameters in f32 or with the matrices already in the compute dtype,
+as ``ServeEngine`` holds them where its copy fits; on those the casts are
+no-ops and the matmuls see the same values.
+
 A hybrid (zamba2) runs its Mamba2 layers in order, each on its sequences'
 state slots (``state``: the pool's conv and SSD arrays and the batch's
 slots) for one token, and before the layers ``hybrid_uses`` names one use of

@@ -130,7 +130,7 @@ METRICS_KEYS = {
     "tokens_prefilled", "submitted", "done", "queue_depth", "used_fraction",
     "frag", "align_hits", "align_misses", "rejected", "cancelled",
     "preemptions", "injected_misses", "maintenance_ns", "compaction_passes",
-    "blocks_migrated",
+    "blocks_migrated", "weights_in_compute_dtype",
 }
 
 STEP_SAMPLE_KEYS = {

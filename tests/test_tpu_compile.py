@@ -8,6 +8,8 @@ one process may load the TPU library, and test workers all import this file.
 import dataclasses
 import functools
 import json
+import math
+import re
 from pathlib import Path
 
 import jax
@@ -21,6 +23,7 @@ from repro.kernels.paged_attention import kernel as paged_k
 from repro.kernels.pud_bulk import kernel as pud_k
 from repro.launch.serve import pool_config
 from repro.models.transformer import LM
+from repro.serve.engine import COMPUTE_DTYPE_LEAVES
 from repro.serve.paged_runner import paged_decode_step
 
 V5E_HBM_BYTES = 16 * 10**9        # one v5e chip, published: 16 GB
@@ -78,14 +81,17 @@ def test_bulk_op_compiles(spec):
     _compile(functools.partial(pud_k.bulk_op, op="maj", interpret=False), x, x, x)
 
 
-def test_paged_decode_step_compiles_at_published_width(spec, monkeypatch):
-    # stablelm-1.6b at its published widths, depth cut to 2 layers
+def _stablelm_step(spec, monkeypatch, matrices=None):
+    """stablelm-1.6b's paged step at its published widths, depth cut to 2
+    layers, compiled for one v5e, and its parameters' shapes; ``matrices``
+    is the dtype of the weight matrices (default: f32, as ``LM.init`` makes
+    them)."""
     cfg = dataclasses.replace(get_config("stablelm_1_6b"), n_layers=2)
     pc = pool_config(cfg, max_seqs=8)
-    params = jax.tree.map(
-        lambda a: spec(a.shape, a.dtype),
-        jax.eval_shape(LM(cfg, attn_impl="naive", remat=None).init, jax.random.key(0)),
-    )
+    shapes = jax.eval_shape(LM(cfg, attn_impl="naive", remat=None).init, jax.random.key(0))
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: spec(a.shape, matrices if matrices and path[-1].key in
+                             COMPUTE_DTYPE_LEAVES else a.dtype), shapes)
     pool = spec(
         (cfg.n_layers, pc.num_blocks, pc.block_size, pc.kv_heads, pc.head_dim),
         jnp.dtype(pc.dtype),
@@ -101,8 +107,39 @@ def test_paged_decode_step_compiles_at_published_width(spec, monkeypatch):
         )
     finally:
         jax.clear_caches()       # drop traces made while the backend was faked
+    return compiled, shapes
+
+
+def test_paged_decode_step_compiles_at_published_width(spec, monkeypatch):
+    compiled, _ = _stablelm_step(spec, monkeypatch)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def _weight_casts(hlo: str, sizes) -> list:
+    """(operand, result) types of every ``convert`` in ``hlo`` whose operand
+    is f32 with as many elements as one of ``sizes``."""
+    types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo))
+    casts = []
+    for dst, src in re.findall(r"= (\w+\[[\d,]*\])\S* convert\(%([\w.\-]+)\)", hlo):
+        t = types.get(src, "")
+        if t.startswith("f32[") and math.prod(int(d) for d in t[4:-1].split(",") if d) in sizes:
+            casts.append((t, dst))
+    return casts
+
+
+def test_paged_step_casts_no_weight_held_in_the_compute_dtype(spec, monkeypatch):
+    # the same step with the matrices in bf16, as ServeEngine holds them where
+    # its copy fits: no weight is cast in the step, and no temporary is larger
+    f32, shapes = _stablelm_step(spec, monkeypatch)
+    bf16, _ = _stablelm_step(spec, monkeypatch, jnp.bfloat16)
+    sizes = set()                 # a matrix, and one layer of a stacked one
+    for path, a in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        if path[-1].key in COMPUTE_DTYPE_LEAVES:
+            sizes |= {math.prod(a.shape)} | ({math.prod(a.shape[1:])} if a.ndim > 2 else set())
+    assert _weight_casts(f32.as_text(), sizes)
+    assert not _weight_casts(bf16.as_text(), sizes)
+    assert bf16.memory_analysis().temp_size_in_bytes <= f32.memory_analysis().temp_size_in_bytes
 
 
 @pytest.mark.parametrize("write", ["token", "prompt"])
