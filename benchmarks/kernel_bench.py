@@ -1,6 +1,6 @@
 """Kernel micro-timings (CPU): jnp reference path wall time + kernel-vs-ref
 agreement.  Interpret-mode Pallas timings are NOT hardware numbers — the TPU
-performance claims live in the roofline analysis; this table tracks the
+kernel times come from the chip benchmark (``chipbench/``); this table tracks the
 reference-path cost and correctness drift per shape."""
 from __future__ import annotations
 

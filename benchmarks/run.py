@@ -6,7 +6,6 @@ aggregator that folds every persisted ``BENCH_*.json`` into one summary.
   * microbench      — paper Figure 2 (zero/copy/aand speedups vs malloc)
   * kv_pool_bench   — TPU adaptation (block-table contiguity per policy)
   * kernel_bench    — kernel reference-path timings + agreement
-  * roofline_report — §Roofline table (requires launch/roofline.py output)
   * translate_bench — vectorized translation/planning fast path vs the seed
                       scalar algorithms (persists BENCH_translate.json)
   * channel_bench   — multi-channel PUD scaling + controller contention
@@ -106,7 +105,6 @@ def main() -> None:
             kernel_bench,
             kv_pool_bench,
             microbench,
-            roofline_report,
             serve_bench,
             trace_bench,
             translate_bench,
@@ -123,7 +121,6 @@ def main() -> None:
             "microbench": lambda: microbench.run(emit),
             "kv_pool": lambda: kv_pool_bench.run(emit),
             "kernel": lambda: kernel_bench.run(emit),
-            "roofline": lambda: roofline_report.run(emit),
             "translate": lambda: translate_bench.run(emit, smoke=args.smoke),
             "channels": lambda: channel_bench.run(emit, smoke=args.smoke),
             "chaos": lambda: chaos_bench.run(emit, smoke=args.smoke),

@@ -2,15 +2,15 @@
 
 One :class:`ModelConfig` per assigned architecture lives in
 ``repro/configs/<id>.py``; ``smoke()`` derives the reduced-config variant
-used by per-arch CPU smoke tests.  Run shapes (the assigned seq/batch cells)
-are :class:`RunShape` instances in ``SHAPES``.
+used by per-arch CPU smoke tests.  A :class:`RunShape` names one batch shape
+(sequence length, global batch, mode) for ``launch/inputs.make_batch``.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["ModelConfig", "RunShape", "SHAPES"]
+__all__ = ["ModelConfig", "RunShape"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,11 +82,6 @@ class ModelConfig:
     @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
-
-    @property
-    def sub_quadratic(self) -> bool:
-        """Can this arch run the long_500k cell? (SSM/hybrid/linear-attn)"""
-        return self.family in ("ssm", "hybrid")
 
     def n_params(self) -> int:
         """Analytic parameter count (embeddings included once)."""
@@ -168,11 +163,3 @@ class RunShape:
     @property
     def is_decode(self) -> bool:
         return self.mode == "decode"
-
-
-SHAPES = {
-    "train_4k": RunShape("train_4k", 4096, 256, "train"),
-    "prefill_32k": RunShape("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": RunShape("decode_32k", 32768, 128, "decode"),
-    "long_500k": RunShape("long_500k", 524288, 1, "decode"),
-}

@@ -1,6 +1,6 @@
 """qwen2-vl-72b [vlm]: 80L d_model=8192 64H (GQA kv=8) d_ff=29568
 vocab=152064 — M-RoPE, dynamic resolution.  Vision frontend is a STUB:
-input_specs() provides precomputed patch embeddings spliced into the token
+make_batch() provides precomputed patch embeddings spliced into the token
 stream.  [arXiv:2409.12191; hf]"""
 from repro.configs.base import ModelConfig
 

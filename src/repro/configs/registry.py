@@ -8,9 +8,9 @@ DRAM organization (channel/bank/subarray counts) validated against
 from __future__ import annotations
 
 import importlib
-from typing import Dict, List
+from typing import List
 
-from repro.configs.base import ModelConfig, RunShape, SHAPES
+from repro.configs.base import ModelConfig
 
 ARCHS: List[str] = [
     "granite_moe_3b_a800m",
@@ -50,14 +50,3 @@ TRACE_ARCHS: List[str] = [
 def moe_archs() -> List[str]:
     """Architectures with a routed-expert MLP (MoE expert dispatch)."""
     return [a for a in lm_archs() if get_config(a).n_experts > 0]
-
-
-def cells(arch: str) -> Dict[str, RunShape]:
-    """The assigned (shape -> RunShape) cells for one arch, with skips."""
-    cfg = get_config(arch)
-    out = {}
-    for sname, shape in SHAPES.items():
-        if sname == "long_500k" and not cfg.sub_quadratic:
-            continue  # quadratic attention: skipped per assignment (DESIGN.md)
-        out[sname] = shape
-    return out

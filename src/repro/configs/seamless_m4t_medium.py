@@ -1,5 +1,5 @@
 """seamless-m4t-medium [audio]: enc-dec, 12L each, d_model=1024 16H (kv=16)
-d_ff=4096 vocab=256206.  Audio frontend is a STUB: input_specs() provides
+d_ff=4096 vocab=256206.  Audio frontend is a STUB: make_batch() provides
 precomputed frame embeddings.  [arXiv:2308.11596; hf]"""
 from repro.configs.base import ModelConfig
 

@@ -2,7 +2,7 @@
 
 One pool holds the KV blocks of *all* live requests for *all* layers:
 
-  K pool: (num_blocks, block_size, kv_heads, head_dim)   per layer-group
+  K pool: (n_layers, num_blocks, block_size, kv_heads, head_dim)
   V pool: same
 
 A request's logical KV stream is a :class:`~repro.core.arena.TileHandle`
@@ -22,9 +22,10 @@ prefill overwrites the whole slot, so admit zeroes nothing.
 
 The device side keeps everything as jnp arrays plus an int32 *block table*
 (max_seqs, max_blocks) — the TPU-idiomatic replacement for the paper's
-re-mmap (see DESIGN.md §2).  `paged_attention` consumes the table; its fast
-path coalesces contiguous block runs into single DMA streams, so PUMA
-placement translates directly into fewer descriptors.
+re-mmap.  `paged_attention` consumes the table and stages one page per grid
+step; PUMA placement keeps consecutive table entries contiguous, so those
+steps' DMAs read HBM in sequence, and it keeps a prompt's pages in few runs,
+which ``write_prompt_kv`` writes one run at a time.
 """
 from __future__ import annotations
 

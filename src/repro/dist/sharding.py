@@ -4,15 +4,14 @@ Model code names *logical* axes ("batch", "embed", "kv_seq", ...); this
 module maps them onto whatever mesh is active.  Everything degrades to a
 no-op when no mesh is set — ``constraint`` returns its input unchanged —
 so single-host tests and the CPU container run the exact same model code
-that the 256/512-chip dry-run compiles.
+as a sharded run (``launch/train.py`` on the production mesh).
 
 Key pieces:
 
 * ``set_mesh``/``use_mesh``/``get_mesh`` — a process-global active mesh
   (``use_mesh`` is the scoped context-manager form).
 * ``PARAM_RULES``/``ACT_RULES`` — mutable logical->mesh-axis dictionaries
-  (parameter axes vs activation axes).  ``override_rules`` /
-  ``override_param_rules`` scope an update and restore on exit.
+  (parameter axes vs activation axes).
 * ``logical_spec(*names)`` — a ``PartitionSpec`` for the active mesh, with
   the "pod" data-parallel axis automatically prepended to the batch entry
   on multi-pod meshes.
@@ -20,8 +19,6 @@ Key pieces:
   mesh-axis product does not evenly divide the corresponding dim is dropped
   to ``None`` (GSPMD would otherwise reject the sharding); short specs are
   padded with ``None`` to the array rank.
-* ``shardings_for`` / ``axes_to_shardings`` — pytree helpers producing
-  ``NamedSharding`` trees for parameter specs and logical-axis-name trees.
 """
 from __future__ import annotations
 
@@ -41,10 +38,6 @@ __all__ = [
     "logical_spec",
     "filter_spec",
     "constraint",
-    "shardings_for",
-    "axes_to_shardings",
-    "override_rules",
-    "override_param_rules",
 ]
 
 #: logical parameter axis -> mesh axis (or tuple of axes, or None=replicated).
@@ -162,64 +155,3 @@ def constraint(x, *names: Optional[str]):
         return x
     spec = filter_spec(logical_spec(*names), x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def _is_spec(x) -> bool:
-    return isinstance(x, P)
-
-
-def shardings_for(specs: Any, abs_tree: Any, mesh) -> Any:
-    """PartitionSpec tree (e.g. from ``spec_tree``) -> NamedSharding tree,
-    divisibility-filtered against the matching abstract arrays."""
-    return jax.tree.map(
-        lambda s, a: NamedSharding(mesh, filter_spec(s, a.shape, mesh)),
-        specs,
-        abs_tree,
-        is_leaf=_is_spec,
-    )
-
-
-def _is_axes_leaf(x) -> bool:
-    # a leaf is a per-dim tuple of logical axis names, e.g. (None, "batch",
-    # "kv_seq", None, None) — or () for scalar leaves.  Containers (dicts,
-    # NamedTuples of such tuples) keep getting traversed.
-    return x is None or (
-        type(x) is tuple and all(e is None or isinstance(e, str) for e in x)
-    )
-
-
-def axes_to_shardings(axes: Any, abs_tree: Any, mesh) -> Any:
-    """Tree of logical-axis-name tuples -> tree of NamedSharding."""
-    abs_leaves, treedef = jax.tree.flatten(abs_tree)
-    axes_leaves = jax.tree.flatten(axes, is_leaf=_is_axes_leaf)[0]
-    assert len(axes_leaves) == len(abs_leaves), (len(axes_leaves), len(abs_leaves))
-    out = []
-    for ax, a in zip(axes_leaves, abs_leaves):
-        names = () if ax is None else ax
-        spec = P(*[_rule_entry(n, mesh, ACT_RULES) for n in names])
-        out.append(NamedSharding(mesh, filter_spec(spec, a.shape, mesh)))
-    return jax.tree.unflatten(treedef, out)
-
-
-@contextlib.contextmanager
-def override_rules(**updates):
-    """Scoped ACT_RULES update (e.g. a shape-specific kv_seq placement)."""
-    saved = dict(ACT_RULES)
-    ACT_RULES.update(updates)
-    try:
-        yield
-    finally:
-        ACT_RULES.clear()
-        ACT_RULES.update(saved)
-
-
-@contextlib.contextmanager
-def override_param_rules(**updates):
-    """Scoped PARAM_RULES update (e.g. inference flips embed -> None)."""
-    saved = dict(PARAM_RULES)
-    PARAM_RULES.update(updates)
-    try:
-        yield
-    finally:
-        PARAM_RULES.clear()
-        PARAM_RULES.update(saved)

@@ -11,8 +11,7 @@ Tiling notes (TPU):
     all matmul dims are MXU-aligned (128 multiples).
   * masking (causal + KV-length) is value-based (-1e30 + multiplicative
     renorm guard) so padded and fully-masked blocks are numerically inert;
-    block *skipping* for causal is a scheduling refinement recorded in
-    EXPERIMENTS.md §Perf.
+    the grid still visits the blocks above the causal diagonal (no skipping).
 """
 from __future__ import annotations
 

@@ -1,9 +1,8 @@
-"""Input builders for every (arch x shape) cell.
+"""Input builders for a (config x run shape) batch.
 
-``input_specs(cfg, shape)`` returns ShapeDtypeStruct stand-ins (weak-type
-correct, shardable, no allocation) for the dry-run; ``make_batch`` builds
-concrete arrays for smoke tests / examples.  Modality frontends are stubs
-per the assignment: [audio] provides precomputed frame embeddings, [vlm]
+``batch_shapes(cfg, shape)`` names each input's shape and dtype;
+``make_batch`` builds concrete arrays for smoke tests / examples.  Modality
+frontends are stubs: [audio] provides precomputed frame embeddings, [vlm]
 precomputed patch embeddings (spliced over the first positions).
 """
 from __future__ import annotations
@@ -39,21 +38,6 @@ def batch_shapes(cfg: ModelConfig, shape: RunShape) -> Dict[str, Any]:
     if cfg.is_encdec and not shape.is_decode:
         out["enc_embeds"] = ((B, shape.seq_len, cfg.d_model), jnp.bfloat16)
     return out
-
-
-def input_specs(cfg: ModelConfig, shape: RunShape) -> Dict[str, jax.ShapeDtypeStruct]:
-    return {
-        k: jax.ShapeDtypeStruct(s, d) for k, (s, d) in batch_shapes(cfg, shape).items()
-    }
-
-
-def abstract_cache(model, shape: RunShape):
-    """ShapeDtypeStruct pytree for the decode cache of one cell."""
-    B = shape.global_batch
-    enc_len = shape.seq_len if model.cfg.is_encdec else 0
-    return jax.eval_shape(
-        lambda: model.init_cache(B, shape.seq_len, enc_len=enc_len)
-    )
 
 
 def make_batch(

@@ -1,13 +1,11 @@
 """Production mesh construction.
 
-Single pod: (16, 16) = 256 chips, axes ("data", "model") — TPU v5e pod.
-Multi-pod:  (2, 16, 16) = 512 chips, axes ("pod", "data", "model") — the
-"pod" axis carries pure data parallelism across the DCN/ICI boundary;
-FSDP stays inside a pod ("data"), tensor/expert parallelism inside a
-16-chip ring ("model").
+One pod: (16, 16) = 256 chips, axes ("data", "model") — a TPU v5e pod.
+FSDP runs over "data", tensor/expert parallelism inside a 16-chip ring
+("model").
 
-This is a FUNCTION (not a module-level constant) so importing never touches
-jax device state — the dry-run sets XLA_FLAGS before any jax import.
+These are FUNCTIONS (not module-level constants) so importing never touches
+jax device state.
 """
 from __future__ import annotations
 
@@ -21,10 +19,8 @@ def make_mesh(shape, axes):
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+def make_production_mesh():
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_smoke_mesh(n_data: int = 2, n_model: int = 4):

@@ -16,10 +16,9 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.configs.registry import get_config, lm_archs
-from repro.core.kv_pool import KVPoolConfig, lane_dim
-from repro.models import mamba2 as M2
+from repro.core.kv_pool import KVPoolConfig
 from repro.models.transformer import LM
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import Request, ServeEngine, pool_fields
 
 #: root of the checkout (``src/repro/launch/serve.py`` -> three levels up)
 REPO_ROOT = Path(__file__).resolve().parents[3]
@@ -42,17 +41,12 @@ def pool_config(
     cfg: ModelConfig, *, max_seqs: int, policy: str = "puma"
 ) -> KVPoolConfig:
     """The serving pool for ``cfg``: 512 pages of 16 tokens (a page fills
-    bf16 sublanes) in ``cfg.kv_cache_dtype``, up to 512 tokens a sequence;
-    a hybrid's pages hold its shared-block uses and its Mamba2 layers get a
-    state slot per sequence."""
-    hybrid = cfg.family == "hybrid"
-    states = {"state_layers": cfg.n_layers, **M2.slot_widths(cfg)} if hybrid else {}
+    bf16 sublanes) in ``cfg.kv_cache_dtype``, up to 512 tokens a sequence,
+    in the geometry the model fixes (``serve.engine.pool_fields``)."""
     return KVPoolConfig(
-        num_blocks=512, block_size=16, kv_heads=cfg.n_kv_heads,
-        head_dim=lane_dim(cfg.hd) if hybrid else cfg.hd, n_layers=cfg.kv_layers,
-        max_seqs=max_seqs,
+        num_blocks=512, block_size=16, max_seqs=max_seqs,
         max_blocks_per_seq=32, blocks_per_arena=64, policy=policy,
-        dtype=cfg.kv_cache_dtype, **states,
+        dtype=cfg.kv_cache_dtype, **pool_fields(cfg),
     )
 
 

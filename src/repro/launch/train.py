@@ -2,7 +2,7 @@
 
 On this CPU container it trains the reduced (smoke) configs; pass
 ``--full`` on real hardware to use the production config, mesh, and
-sharding rules (same code path the dry-run compiles for 256/512 chips).
+sharding rules (the (16, 16) mesh of ``launch/mesh.make_production_mesh``).
 """
 from __future__ import annotations
 

@@ -10,8 +10,8 @@
 
 Decode mode consumes/produces an explicit KV cache
 ``(k, v): (B, S_max, KV, hd)`` plus the current length, updating in place
-with dynamic_update_slice — the dense-cache path used by the dry-run; the
-serving engine swaps in the PUMA paged pool on-line.
+with dynamic_update_slice — the dense-cache path the serving engine's
+prefill runs; its decode steps swap in the PUMA paged pool.
 """
 from __future__ import annotations
 

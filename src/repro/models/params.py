@@ -1,26 +1,22 @@
 """Declarative parameter trees with logical sharding axes.
 
 A module is a (nested) dict of :class:`ParamDef`; ``init_params`` turns it
-into a pytree of arrays and ``spec_tree`` into a matching pytree of
-``PartitionSpec`` via logical->mesh axis rules (MaxText-style).  This keeps
-model code framework-free (pure functions over dicts) while making every
-parameter's sharding a first-class, greppable property.
+into a pytree of arrays.  Each definition also names a logical axis per
+dimension; no code maps those names onto a mesh.  This keeps model code
+framework-free (pure functions over dicts).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 __all__ = [
     "ParamDef",
     "init_params",
-    "spec_tree",
-    "DEFAULT_RULES",
     "count_params",
 ]
 
@@ -59,20 +55,6 @@ def init_params(key: jax.Array, defs: Any) -> Any:
     keys = jax.random.split(key, len(leaves))
     out = [_init_leaf(k, d) for k, d in zip(keys, leaves)]
     return jax.tree.unflatten(treedef, out)
-
-
-#: canonical parameter-axis rules live in repro.dist.sharding.PARAM_RULES
-#: (mutable + context-overridable, e.g. inference flips embed->None).
-from repro.dist.sharding import PARAM_RULES as DEFAULT_RULES
-
-
-def spec_tree(defs: Any, rules: Optional[Dict[str, Any]] = None) -> Any:
-    rules = {**DEFAULT_RULES, **(rules or {})}
-
-    def one(d: ParamDef) -> P:
-        return P(*[rules.get(a) if a is not None else None for a in d.axes])
-
-    return jax.tree.map(one, defs, is_leaf=lambda x: isinstance(x, ParamDef))
 
 
 def count_params(tree: Any) -> int:

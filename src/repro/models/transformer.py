@@ -3,15 +3,13 @@
 One :class:`LM` object per config exposes pure functions:
 
   * ``init(key) -> params``           (stacked-layer pytree, scan-ready)
-  * ``param_specs(mesh) -> pytree[PartitionSpec]``
   * ``train_loss(params, batch)``     (teacher-forced CE + MoE aux)
   * ``prefill_logits(params, batch)`` (last-position logits)
   * ``decode_step(params, batch, cache) -> (logits, cache)``
-  * ``init_cache(batch, max_len) / cache_specs(mesh)``
+  * ``init_cache(batch, max_len)``
 
-Layers are *stacked* (leading "layers" axis) and driven by ``lax.scan`` so
-an 88-layer model lowers its block exactly once — the difference between a
-40 s and a 40 min dry-run compile.  Remat wraps the scan body.
+Layers are *stacked* (leading "layers" axis) and driven by ``lax.scan``, so
+a deep model lowers its block exactly once.  Remat wraps the scan body.
 
 Families:
   dense   — [norm-attn-res, norm-mlp-res] x L (GQA/MQA, RoPE variants)
@@ -25,20 +23,19 @@ Families:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.dist.sharding import constraint, get_mesh, logical_spec
+from repro.dist.sharding import constraint
 from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import mamba2 as M2
 from repro.models import rwkv6 as R6
 from repro.models.attention import apply_attention, attn_defs
-from repro.models.params import ParamDef, init_params, spec_tree
+from repro.models.params import ParamDef, init_params
 
 __all__ = ["LM", "shared_block_input", "shared_block_mlp"]
 
@@ -55,28 +52,6 @@ def stack_defs(defs: Any, n: int) -> Any:
         )
 
     return jax.tree.map(one, defs, is_leaf=lambda x: isinstance(x, ParamDef))
-
-
-def scan_or_loop(body, carry, xs, use_scan: bool):
-    """lax.scan, or an unrolled python loop over the leading axis.
-
-    The unrolled form exists for HLO cost accounting: XLA's cost analysis
-    counts a while-loop body *once*, so the roofline pipeline compiles small
-    unrolled variants to recover exact per-layer costs (launch/roofline.py).
-    """
-    if use_scan:
-        return jax.lax.scan(body, carry, xs)
-    leaves = [x for x in jax.tree.leaves(xs) if hasattr(x, "shape")]
-    n = leaves[0].shape[0]
-    ys = []
-    for i in range(n):
-        xi = jax.tree.map(lambda a: a[i], xs)
-        carry, y = body(carry, xi)
-        ys.append(y)
-    if all(jax.tree.structure(y).num_leaves == 0 for y in ys):
-        return carry, ys[0]
-    stacked = jax.tree.map(lambda *zs: jnp.stack(zs, axis=0), *ys)
-    return carry, stacked
 
 
 def _remat(fn, policy: Optional[str]):
@@ -213,14 +188,10 @@ class LM:
         *,
         attn_impl: str = "naive",
         remat: Optional[str] = "full",
-        rules: Optional[Dict] = None,
-        scan_layers: bool = True,
     ):
         self.cfg = cfg
         self.attn_impl = attn_impl
         self.remat = remat
-        self.rules = rules or {}
-        self.scan_layers = scan_layers
         self.dtype = jnp.dtype(cfg.dtype)
 
     # -- parameter definitions ------------------------------------------------
@@ -279,9 +250,6 @@ class LM:
         params = init_params(key, self.param_defs())
         return jax.tree.map(lambda x: x.astype(jnp.float32), params)
 
-    def param_specs(self) -> Any:
-        return spec_tree(self.param_defs(), self.rules)
-
     # -- forward helpers --------------------------------------------------------
     def _embed_inputs(self, params, batch) -> Tuple[jax.Array, jax.Array]:
         cfg = self.cfg
@@ -332,10 +300,8 @@ class LM:
                 return (xc, aux), new_cache
 
             body = _remat(body, self.remat)
-            (x, aux), new_caches = scan_or_loop(
-                body, (x, jnp.zeros((), jnp.float32)), (params["decoder"], caches),
-                self.scan_layers,
-            )
+            (x, aux), new_caches = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32)), (params["decoder"], caches))
             return x, new_caches, aux
 
         if cfg.family == "ssm":
@@ -346,10 +312,8 @@ class LM:
                 return (xc, aux + a), new_st
 
             body = _remat(body, self.remat)
-            (x, aux), new_caches = scan_or_loop(
-                body, (x, jnp.zeros((), jnp.float32)), (params["layers"], caches),
-                self.scan_layers,
-            )
+            (x, aux), new_caches = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32)), (params["layers"], caches))
             return x, new_caches, aux
 
         if cfg.family == "hybrid":
@@ -362,10 +326,8 @@ class LM:
             return (xc, aux + a), new_cache
 
         body = _remat(body, self.remat)
-        (x, aux), new_caches = scan_or_loop(
-            body, (x, jnp.zeros((), jnp.float32)), (params["layers"], caches),
-            self.scan_layers,
-        )
+        (x, aux), new_caches = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32)), (params["layers"], caches))
         return x, new_caches, aux
 
     def _encoder_kv(self, attn_params, enc_out):
@@ -442,7 +404,7 @@ class LM:
             return xc + L.apply_mlp(lp["mlp"], h), None
 
         body = _remat(body, self.remat)
-        x, _ = scan_or_loop(body, x, params["encoder"], self.scan_layers)
+        x, _ = jax.lax.scan(body, x, params["encoder"])
         return L.apply_norm(params["enc_ln"], x)
 
     # -- public entry points ------------------------------------------------------
@@ -618,56 +580,3 @@ class LM:
             "len": jnp.zeros((), jnp.int32),
             "len_rec": jnp.zeros((), jnp.int32),
         }
-
-    def cache_spec_axes(self):
-        """Logical axis names per cache leaf (for sharding the dry-run)."""
-        cfg = self.cfg
-
-        def kv_axes(leaf_ndim):
-            # kv_seq carries the "model" axis; heads stay unsharded in the
-            # cache (sharding both would double-book "model").
-            return (None, "batch", "kv_seq", None, None)
-
-        def split_axes():
-            return {
-                "main": (kv_axes(5), kv_axes(5)),
-                # recent ring is batch-sharded only: its appends must never
-                # touch a sharded dim
-                "recent": (
-                    (None, "batch", None, None, None),
-                    (None, "batch", None, None, None),
-                ),
-            }
-
-        if cfg.is_encdec:
-            return {
-                "layers": {
-                    "self": split_axes(),
-                    "cross": (kv_axes(5), kv_axes(5)),
-                },
-                "len": (),
-                "len_rec": (),
-                "enc_len": (),
-            }
-        if cfg.family == "ssm":
-            return {
-                "layers": R6.RwkvState(
-                    shift_tm=(None, "batch", None),
-                    shift_cm=(None, "batch", None),
-                    wkv=(None, "batch", "heads", None, None),
-                ),
-                "len": (),
-            }
-        if cfg.family == "hybrid":
-            return {
-                "layers": {
-                    "mamba": M2.MambaState(
-                        conv=(None, "batch", None, "mlp"),
-                        ssd=(None, "batch", "heads", None, None),
-                    ),
-                    "attn": split_axes(),
-                },
-                "len": (),
-                "len_rec": (),
-            }
-        return {"layers": split_axes(), "len": (), "len_rec": ()}

@@ -106,13 +106,14 @@ from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.kv_pool import KVPoolConfig, PagedKVPool, lane_dim
+from repro.models import mamba2 as M2
 from repro.robustness import (
     ClientCancelled,
     DeadlineExceeded,
     EngineStalled,
     RequestRejected,
 )
-from repro.serve.paged_runner import paged_decode_step, paged_decode_step_jit
+from repro.serve.paged_runner import paged_decode_step_jit
 
 if TYPE_CHECKING:
     from repro.robustness.faults import FaultInjector
@@ -144,6 +145,20 @@ def cast_fits(needed: int, stats: Optional[Dict[str, int]]) -> bool:
 def _memory_stats(device) -> Optional[Dict[str, int]]:
     """The device's memory stats (a test plants a full device here)."""
     return device.memory_stats()
+
+
+def pool_fields(cfg: ModelConfig) -> Dict[str, int]:
+    """The ``KVPoolConfig`` fields a model fixes: pages for its KV layers
+    with its KV heads and head dimension, and a hybrid's state slots.  A
+    hybrid's pages pad the head dimension to whole lane tiles
+    (``lane_dim``), and each of its Mamba2 layers keeps a slot per sequence
+    (``mamba2.slot_widths``)."""
+    fields = {"kv_heads": cfg.n_kv_heads, "head_dim": cfg.hd, "n_layers": cfg.kv_layers,
+              "state_layers": 0, "conv_width": 0, "ssd_heads": 0, "ssd_width": 0}
+    if cfg.family == "hybrid":
+        fields.update(head_dim=lane_dim(cfg.hd), state_layers=cfg.n_layers,
+                      **M2.slot_widths(cfg))
+    return fields
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -205,8 +220,6 @@ class ServeEngine:
         params,
         pool_cfg: KVPoolConfig,
         *,
-        jit: bool = True,           # compile prefill/decode per shape (load-
-                                    # harness scale needs it; False = eager)
         eos_id: Optional[int] = None,
         injector: Optional["FaultInjector"] = None,
         admission_lookahead: int = 8,
@@ -218,14 +231,10 @@ class ServeEngine:
         if cfg.family in ("ssm", "encdec") or cfg.is_encdec:
             raise ValueError(f"{cfg.name}: the paged engine serves attention and "
                              f"Mamba2-hybrid families, not {cfg.family}")
-        hybrid = cfg.family == "hybrid"
-        want = (cfg.n_kv_heads, lane_dim(cfg.hd) if hybrid else cfg.hd, cfg.kv_layers,
-                cfg.n_layers if hybrid else 0)
-        have = (pool_cfg.kv_heads, pool_cfg.head_dim, pool_cfg.n_layers,
-                pool_cfg.state_layers)
-        if have != want:
-            raise ValueError(f"pool (kv_heads, head_dim, KV layers, state layers) "
-                             f"{have} does not fit {cfg.name}: {want}")
+        bad = {k: (getattr(pool_cfg, k), v) for k, v in pool_fields(cfg).items()
+               if getattr(pool_cfg, k) != v}
+        if bad:
+            raise ValueError(f"pool does not fit {cfg.name}, (pool, model): {bad}")
         self.model = model
         self.cfg = cfg
         self.pool = PagedKVPool(pool_cfg, injector=injector)
@@ -233,21 +242,15 @@ class ServeEngine:
         #: the Pallas kernels on the chip; elsewhere the jnp reference (the
         #: kernels would only run in the slow Pallas interpreter there)
         self.use_kernel = jax.default_backend() == "tpu"
-        self.jit = jit
-        if jit:
-            # cache the jitted prefill step ON the model so every engine
-            # over the same model shares one XLA cache (scenario reruns
-            # compile nothing); the paged step's shared wrapper lives in
-            # paged_runner for the same reason.
-            fn = getattr(model, "_jit_decode_step", None)
-            if fn is None:
-                fn = jax.jit(model.decode_step)
-                model._jit_decode_step = fn
-            self._decode_step = fn
-            self._paged_step = paged_decode_step_jit
-        else:
-            self._decode_step = model.decode_step
-            self._paged_step = paged_decode_step
+        # the jitted prefill step is cached ON the model so every engine
+        # over the same model shares one XLA cache (scenario reruns compile
+        # nothing); the paged step's shared wrapper lives in paged_runner
+        # for the same reason
+        fn = getattr(model, "_jit_decode_step", None)
+        if fn is None:
+            fn = model._jit_decode_step = jax.jit(model.decode_step)
+        self._decode_step = fn
+        self._paged_step = paged_decode_step_jit
         self.eos_id = eos_id
         self.admission_lookahead = max(1, admission_lookahead)
         self.stall_patience = max(1, stall_patience)

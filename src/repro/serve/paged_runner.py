@@ -6,9 +6,8 @@ KV through the *block table* (re-mmap analogue) with the
 back into pool blocks placed by the PUMA policy.
 
 The runner mirrors ``LM.decode_step`` exactly (same params, same math) with
-the dense cache swapped for (k_pool, v_pool, block_table, seq_lens); layer
-loop is unrolled (serving configs are small; the dry-run path uses the
-scanned dense-cache step).
+the dense cache swapped for (k_pool, v_pool, block_table, seq_lens); the
+layer loop is unrolled (serving configs are small).
 
 Every weight matrix is cast to ``cfg.dtype`` where it is used, so the step
 takes parameters in f32 or with the matrices already in the compute dtype,

@@ -1,4 +1,4 @@
-"""The jit'd train / serve step builders (shared by trainer, dryrun, bench).
+"""The jit'd train / serve step builders (shared by the trainer and tests).
 
 ``build_train_step`` returns a donated, fully-sharded
 ``(params, opt_state, [err_state], batch) -> (params, opt_state, metrics)``.

@@ -138,20 +138,29 @@ def test_rotate_half_rotary():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_engine_takes_hybrids_and_refuses_recurrent_and_encdec():
+@pytest.mark.parametrize("arch", ["stablelm_1_6b", "zamba2_7b"])
+def test_engine_takes_hybrids_and_refuses_recurrent_and_encdec(arch):
     from repro.launch.serve import pool_config
     from repro.serve.engine import ServeEngine
 
-    for arch in ("rwkv6_7b", "seamless_m4t_medium"):
-        cfg = get_config(arch).smoke()
+    for other in ("rwkv6_7b", "seamless_m4t_medium"):
+        cfg = get_config(other).smoke()
         with pytest.raises(ValueError, match="paged engine"):
             ServeEngine(LM(cfg), None, pool_config(cfg, max_seqs=2))
-    cfg = get_config("zamba2_7b").smoke()
+    cfg = get_config(arch).smoke()
     pc = pool_config(cfg, max_seqs=2)
-    assert (pc.n_layers, pc.head_dim, pc.state_layers) == (3, lane_dim(cfg.hd), 5)
-    assert (pc.conv_width, pc.ssd_heads, pc.ssd_width) == (3 * (256 + 2 * 2 * 16), 16, 16 * 16)
     model = LM(cfg, attn_impl="naive", remat=None)
     eng = ServeEngine(model, model.init(jax.random.key(0)), pc)
-    assert eng.pool.ssd.shape == (2, 5, 16, 256)
-    with pytest.raises(ValueError, match="does not fit"):
-        ServeEngine(model, None, dataclasses.replace(pc, n_layers=5))
+    if cfg.family == "hybrid":
+        assert (pc.n_layers, pc.head_dim, pc.state_layers) == (3, lane_dim(cfg.hd), 5)
+        assert (pc.conv_width, pc.ssd_heads, pc.ssd_width) == (
+            3 * (256 + 2 * 2 * 16), 16, 16 * 16)
+        assert eng.pool.ssd.shape == (2, 5, 16, 256)
+    else:
+        assert (pc.kv_heads, pc.head_dim, pc.n_layers) == (cfg.n_kv_heads, cfg.hd, cfg.n_layers)
+        assert (pc.state_layers, pc.conv_width, pc.ssd_heads, pc.ssd_width) == (0, 0, 0, 0)
+        assert eng.pool.ssd is None
+    for field in ("kv_heads", "head_dim", "n_layers", "state_layers", "ssd_width"):
+        bad = dataclasses.replace(pc, **{field: getattr(pc, field) + 2})
+        with pytest.raises(ValueError, match=f"does not fit.*'{field}'"):
+            ServeEngine(model, None, bad)
